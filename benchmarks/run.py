@@ -36,6 +36,8 @@ import subprocess
 import time
 import traceback
 
+import jax
+
 from benchmarks import (backend_parity, compiler_report, fault_injection,
                         fig6_channels, fig10_switching, fig11_energy,
                         llm_serving, roofline_report, serving_load,
@@ -62,6 +64,15 @@ BENCHES = {
 }
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def use_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache where
+    JAX_COMPILATION_CACHE_DIR says (JAX reads the variable itself), or
+    else at one fixed path in the checkout, so later runs hit it."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(REPO_ROOT, ".jax_cache"))
 
 
 def _headline(name: str, res: dict) -> str:
@@ -198,6 +209,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="results/bench")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     names = (args.only.split(",") if args.only else list(BENCHES))
     os.makedirs(args.out, exist_ok=True)
     git_sha = _git_sha()

@@ -34,8 +34,11 @@ Gating under ``run.py --compare`` (see ``SPEED_CHECKS`` /
   runner never diffs speedups against an 8-core baseline.
 
 The measurement runs in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=<N>`` so it works no
-matter how the parent process initialized jax.
+``XLA_FLAGS=--xla_force_host_platform_device_count=<N>`` and
+``JAX_PLATFORMS=cpu``, so it works no matter how the parent process
+initialized jax and never reaches for an accelerator the parent may
+hold.  Every number it reports is therefore a CPU number (emulated
+host devices), not a chip number.
 
     PYTHONPATH=src python benchmarks/sharding_scaling.py [--smoke]
 """
@@ -238,9 +241,12 @@ def _measure(cfg: dict) -> dict:
             f"devices share cores, so wall-clock speedup cannot "
             f"materialize here; bit-exactness and the packed-traffic "
             f"ratios still gate")
+    dev = jax.devices()
     return {
         "config": {**cfg, "host_cores": n_cores,
                    "layers": len(prog.layers)},
+        "device": {"platform": dev[0].platform, "kind": dev[0].device_kind,
+                   "count": len(dev)},
         "throughput_img_s": throughput,
         "speedup_vs_1dev": speedup,
         "filter_throughput_img_s": filter_tp,
@@ -265,6 +271,9 @@ def run(smoke: bool = False) -> dict:
     flags = [f for f in env.get("XLA_FLAGS", "").split()
              if not f.startswith(_FLAG)]
     env["XLA_FLAGS"] = " ".join(flags + [f"{_FLAG}={N_DEVICES}"])
+    # Host devices only: a chip belongs to one process, and the parent
+    # may hold it.
+    env["JAX_PLATFORMS"] = "cpu"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     src = os.path.join(root, "src")
     env["PYTHONPATH"] = os.pathsep.join(
@@ -282,8 +291,12 @@ def run(smoke: bool = False) -> dict:
 
 def report(res: dict) -> str:
     cfg = res["config"]
+    dev = res["device"]
     lines = [
         "## Sharded multi-device scaling (CIFAR CutieProgram)",
+        "",
+        f"CPU numbers: {dev['count']} emulated {dev['platform']} host "
+        "devices, not a chip measurement.",
         "",
         f"width={cfg['width']}, batch={cfg['batch']}, "
         f"{cfg['layers']} layers, {cfg['host_cores']} host cores",

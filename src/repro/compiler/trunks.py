@@ -93,9 +93,10 @@ def trunk_vmem_bytes(layers, in_shape) -> int:
     per-channel thresholds, the two padded ping-pong activation buffers
     (sized by the trunk's *first* layer — dims only shrink), the
     kernel's input/output blocks, and — the dominant transient — the
-    float32 im2col patch (N*OH*OW x K*K*Cin) plus accumulator that each
-    layer's completely-unrolled window dot materializes (its largest
-    layer bounds the peak; only one layer's patch is live at a time).
+    float32 im2col patch (N*H*W x K*K*Cin, gathered at stride 1 over the
+    layer's input dims) plus accumulator that each layer's
+    completely-unrolled window dot materializes (its largest layer bounds
+    the peak; only one layer's patch is live at a time).
     """
     n, h, w, _ = in_shape
     k = layers[0].kernel_size
@@ -107,10 +108,8 @@ def trunk_vmem_bytes(layers, in_shape) -> int:
     scratch = 2 * n * (h + 2 * p) * (w + 2 * p) * cin
     shapes = segment_shapes(layers, (h, w))
     transient = 0
-    for i, instr in enumerate(layers):
-        oh, ow = engine.conv_out_hw(instr, *shapes[i])   # pre-pool dims
-        transient = max(transient,
-                        n * oh * ow * (k * k * cin + cout) * 4)
+    for hi, wi in shapes[:-1]:      # windows gathered at stride 1
+        transient = max(transient, n * hi * wi * (k * k * cin + cout) * 4)
     oh, ow = shapes[-1]
     io = n * h * w * cin + n * oh * ow * cout
     return weights + thresholds + scratch + transient + io

@@ -59,6 +59,18 @@ def unpack_trits(b: Array, n: int) -> Array:
     return trits[:n].astype(jnp.int8)
 
 
+def pack_rows(t: Array) -> Array:
+    """(R, n) trits -> (R, ceil(n/5)) bytes, one codec stream per row.
+
+    Each row is zero-padded to a multiple of 5 on its own, so every row
+    decodes independently — the layout the Pallas kernels decode next to
+    their compute (`repro.kernels.trit_codec.unpack_rows`).
+    """
+    r, n = t.shape
+    t = jnp.pad(t, ((0, 0), (0, (-n) % TRITS_PER_BYTE)))
+    return pack_trits(t.reshape(-1)).reshape(r, -1)
+
+
 def pack_filter_rows(w: Array) -> Array:
     """(K, K, Cin, Cout) trits -> (Cout, ceil(K*K*Cin/5)) packed rows.
 
@@ -69,10 +81,7 @@ def pack_filter_rows(w: Array) -> Array:
     over output channels and decodes next to its taps.
     """
     k, _, cin, cout = w.shape
-    flat = jnp.transpose(w, (3, 0, 1, 2)).reshape(cout, k * k * cin)
-    pad = (-flat.shape[1]) % TRITS_PER_BYTE
-    flat = jnp.pad(flat, ((0, 0), (0, pad)))
-    return pack_trits(flat.reshape(-1)).reshape(cout, -1)
+    return pack_rows(jnp.transpose(w, (3, 0, 1, 2)).reshape(cout, -1))
 
 
 def pack_tensor(x: Array) -> tuple[Array, tuple[int, ...]]:

@@ -13,8 +13,11 @@ registers/VMEM, so pre-threshold integers never spill to HBM:
   per-channel constant).
 
 Bit-identical to the jnp reference pair ``engine._pool_pre_threshold`` +
-``folding.apply_thresholds``, but written kernel-safe: strided slices
-instead of 5-D window reshapes, int8 flags instead of bool arrays.
+``folding.apply_thresholds``, but written so the TPU compiler (Mosaic)
+accepts it: pooling is a reshape-and-reduce over the window (Mosaic only
+lowers unit-stride slices), and the compares fold the per-channel flip
+into a +-1 sign instead of selecting between boolean arrays (Mosaic
+cannot lower a select of i1 vectors).
 Per-channel vectors broadcast against ``(..., C)`` accumulators, so both
 the per-layer kernels (one image per grid step) and the trunk kernel
 (whole batch) share it unchanged.
@@ -26,8 +29,56 @@ import jax
 import jax.numpy as jnp
 
 
+def channel_sign(flip, dtype):
+    """sign(g) per channel: -1 where the compare direction flips, else 1."""
+    return 1 - 2 * (flip != 0).astype(dtype)
+
+
+def fit_axis(z, axis: int, n: int):
+    """Crop, or zero-pad at the end, ``z`` along ``axis`` to length ``n``."""
+    m = z.shape[axis]
+    if m >= n:
+        return jax.lax.slice_in_dim(z, 0, n, axis=axis)
+    pad = list(z.shape)
+    pad[axis] = n - m
+    return jnp.concatenate([z, jnp.zeros(pad, z.dtype)], axis=axis)
+
+
+def _window_reduce(z, win, out_hw, reduce):
+    """Reduce each non-overlapping ``win`` = (wh, ww) window of z
+    (N, H, W, C) to one value: (N, OH, OW, C) with (OH, OW) = out_hw.
+
+    Rows and columns past ``win * out_hw`` are dropped (cropped windows)
+    and missing ones zero-filled, so only whole windows reach
+    ``reduce(a, axis)``.  Splitting a dimension by reshape is the form
+    Mosaic lowers; a strided slice is not.
+    """
+    n, _, _, c = z.shape
+    (oh, ow), (wh, ww) = out_hw, win
+    z = fit_axis(fit_axis(z, 1, oh * wh), 2, ow * ww)
+    z = reduce(z.reshape(n * oh, wh, ow * ww, c), 1)
+    z = reduce(z.reshape(n * oh, ow, ww, c), 2)
+    return z.reshape(n, oh, ow, c)
+
+
+def _first(a, axis: int):
+    return jax.lax.index_in_dim(a, 0, axis, keepdims=False)
+
+
+def subsample(z, stride, out_hw):
+    """Stride a stride-1 conv result: z (N, H, W, C) -> every
+    ``stride``-th row and column, (N, OH, OW, C) with (OH, OW) = out_hw.
+
+    A strided conv equals the stride-1 conv sampled at multiples of the
+    stride, so kernels compute the stride-1 accumulator and keep the
+    first element of each stride x stride window."""
+    if tuple(stride) == (1, 1):
+        return z
+    return _window_reduce(z, stride, out_hw, _first)
+
+
 def pool_int(z, flip, pool):
-    """Merged pooling on int32 pre-activations z (N, OH, OW, C).
+    """Merged pooling on integer pre-activations z (N, OH, OW, C).
 
     Windows that do not fit are cropped (exactly like the reference
     ``engine._pool_pre_threshold``).  ``flip`` is the per-channel compare
@@ -42,42 +93,34 @@ def pool_int(z, flip, pool):
             f"pool window {win} exceeds the {oh}x{ow} conv output; "
             "run CutieProgram.validate(in_shape=...) to catch this at "
             "compile time")
-    parts = []
-    for i in range(win):                      # unrolled window taps
-        for j in range(win):
-            parts.append(jax.lax.slice(
-                z, (0, i, j, 0),
-                (n, i + win * (ph - 1) + 1, j + win * (pw - 1) + 1, c),
-                (1, win, win, 1)))            # (N, PH, PW, C)
-    if kind == "avg":
-        acc = parts[0]
-        for p in parts[1:]:
-            acc = acc + p                     # thresholds pre-scaled
-        return acc
-    sgn = jnp.where(flip != 0, -1, 1).astype(z.dtype)
-    acc = parts[0] * sgn
-    for p in parts[1:]:
-        acc = jnp.maximum(acc, p * sgn)
-    return acc * sgn
+    if kind == "avg":                         # thresholds pre-scaled
+        return _window_reduce(z, (win, win), (ph, pw), jnp.sum)
+    sgn = channel_sign(flip, z.dtype)
+    return _window_reduce(z * sgn, (win, win), (ph, pw), jnp.max) * sgn
 
 
 def two_threshold(z, t_lo, t_hi, flip):
-    """Folded two-threshold ternarize of an integer accumulator."""
-    zf = z.astype(jnp.float32)
-    fl = flip != 0
-    pos = jnp.where(fl, zf < t_hi, zf > t_hi)
-    neg = jnp.where(fl, zf > t_lo, zf < t_lo)
-    return pos.astype(jnp.int8) - neg.astype(jnp.int8)
+    """Folded two-threshold ternarize of an integer accumulator -> int32.
+
+    Comparing sign(g)*z against sign(g)*t is the flipped compare: the
+    negation is exact in float32."""
+    sgn = channel_sign(flip, jnp.float32)
+    sz = sgn * z.astype(jnp.float32)
+    return ((sz > sgn * t_hi).astype(jnp.int32)
+            - (sz < sgn * t_lo).astype(jnp.int32))
 
 
 def const_fixup(y, const, is_const):
     """Degenerate (g == 0) channels take their stored constant trit."""
-    return jnp.where(is_const != 0, const.astype(jnp.int8), y)
+    keep = (is_const != 0).astype(y.dtype)
+    return y + keep * (const.astype(y.dtype) - y)
 
 
 def zero_count(x) -> jnp.ndarray:
-    """Scalar int32 count of zero trits in x (kernel-safe, exact)."""
-    return jnp.sum((x == 0).astype(jnp.int32), dtype=jnp.int32)
+    """Scalar int32 count of zero trits in x (kernel-safe, exact; the TPU
+    compares 32-bit lanes only, so int8 trits widen first)."""
+    return jnp.sum((x.astype(jnp.int32) == 0).astype(jnp.int32),
+                   dtype=jnp.int32)
 
 
 def _coverage(idx, n_anchor: int, k: int):
@@ -114,7 +157,7 @@ def window_toggle_count(xp, k: int, oh: int, ow: int, cin: int
     jumps — are summed directly.  O(PH*PW*C) instead of O(OH*OW*K*K*C).
     """
     ph, pw = oh + k - 1, ow + k - 1
-    x = jax.lax.slice(xp, (0, 0, 0), (ph, pw, cin))
+    x = jax.lax.slice(xp, (0, 0, 0), (ph, pw, cin)).astype(jnp.int32)
     total = jnp.int32(0)
     if ow > 1:
         d = jnp.sum((jax.lax.slice(x, (0, 1, 0), (ph, pw, cin))
@@ -147,4 +190,4 @@ def layer_epilogue(z, t_lo, t_hi, flip, const=None, is_const=None,
     y = two_threshold(z, t_lo, t_hi, flip)
     if const is not None:
         y = const_fixup(y, const, is_const)
-    return y
+    return y.astype(jnp.int8)

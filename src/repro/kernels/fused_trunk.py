@@ -38,11 +38,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.codec import TRITS_PER_BYTE, packed_size
+from repro.core.codec import packed_size
 from repro.core.engine import conv_out_dims, layer_out_dims
 from repro.kernels import epilogue as epi
 from repro.kernels import trit_codec as C
-from repro.kernels._compat import compiler_params
 
 
 def trunk_shapes(in_hw, k: int, metas) -> list[tuple[int, int]]:
@@ -62,17 +61,6 @@ def trunk_shapes(in_hw, k: int, metas) -> list[tuple[int, int]]:
     return shapes
 
 
-def _unpack_bytes(v, numel: int):
-    """(G,) packed bytes -> (numel,) int8 trits (codec layout, in-VMEM)."""
-    return C.unpack_digits(v).reshape(-1)[:numel].astype(jnp.int8)
-
-
-def _pack_trits(t):
-    """(5*G,) int8 trits -> (G,) packed bytes (codec layout, in-VMEM)."""
-    d = (t.astype(jnp.int32) + 1).reshape(-1, TRITS_PER_BYTE)
-    return C.pack_digits(d)
-
-
 def _trunk_kernel(x_ref, w_ref, tlo_ref, thi_ref, flip_ref, const_ref,
                   isc_ref, o_ref, *rest, k: int, metas, shapes,
                   unpack_shape, pack_out: bool, stats_cin):
@@ -84,11 +72,11 @@ def _trunk_kernel(x_ref, w_ref, tlo_ref, thi_ref, flip_ref, const_ref,
     exactly zero and meet only zero weight rows downstream.
 
     With ``unpack_shape`` the kernel input is 5-trits/byte packed bytes
-    (the previous trunk's output) decoded here in VMEM; with
-    ``pack_out`` the final trit map is packed before the writeback — so
-    the only tensor that crosses HBM between two fused trunks is the
-    packed byte stream (paper §III-A's 1.6 bits/trit on the feature-map
-    path).
+    (the previous trunk's output, one codec row per pixel) decoded here
+    in VMEM; with ``pack_out`` the final trit map is packed before the
+    writeback — so the only tensor that crosses HBM between two fused
+    trunks is the packed bytes (paper §III-A's 1.6 bits/trit on the
+    feature-map path).
 
     With ``stats_cin`` (the head layer's *logical* Cin) a second output
     ref rides along and receives per-layer int32 switching counters —
@@ -109,15 +97,13 @@ def _trunk_kernel(x_ref, w_ref, tlo_ref, thi_ref, flip_ref, const_ref,
     if unpack_shape is None:
         a_ref[:, p:p + h, p:p + w, :] = x_ref[...]
     else:
-        numel = 1
-        for d in unpack_shape:
-            numel *= d
-        trits = _unpack_bytes(x_ref[...], numel).reshape(unpack_shape)
-        a_ref[:, p:p + h, p:p + w, :unpack_shape[-1]] = trits
+        cin = unpack_shape[-1]
+        trits = C.unpack_rows(x_ref[...], 0, cin)   # (N*H*W, Cin)
+        a_ref[:, p:p + h, p:p + w, :cin] = trits.reshape(
+            unpack_shape).astype(jnp.int8)
     src, dst = a_ref, b_ref
     for l, (stride, pool) in enumerate(metas):
         h, w = shapes[l]
-        sh, sw = stride
         oh, ow = conv_out_dims(k, stride, True, h, w)
         xp = src[:, :h + 2 * p, :w + 2 * p, :]      # padded view, in VMEM
         if s_ref is not None:
@@ -135,29 +121,27 @@ def _trunk_kernel(x_ref, w_ref, tlo_ref, thi_ref, flip_ref, const_ref,
         # partial sums are integers bounded by K*K*C (+ pool window sums,
         # <= ~2e4) << 2^24, so every value is exactly representable and
         # the result is bit-identical to int32 accumulation, while the
-        # whole-batch (N*OH*OW, K*K*C) gemm runs at full gemm throughput.
-        wins = [jax.lax.slice(
-            xp, (0, kh, kw, 0),
-            (n, kh + sh * (oh - 1) + 1, kw + sw * (ow - 1) + 1, cu),
-            (1, sh, sw, 1))                         # (N, OH, OW, Cu)
-            for kh in range(k) for kw in range(k)]
+        # whole-batch (N*H*W, K*K*C) gemm runs at full gemm throughput.
+        # Windows are gathered at stride 1 (the TPU compiler lowers only
+        # unit-stride slices); a strided layer then keeps every
+        # stride-th accumulator row and column.
+        wins = [xp[:, kh:kh + h, kw:kw + w, :]      # (N, H, W, Cu)
+                for kh in range(k) for kw in range(k)]
         patch = jnp.concatenate(wins, axis=-1).reshape(
-            n * oh * ow, k * k * cu).astype(jnp.float32)
+            n * h * w, k * k * cu).astype(jnp.float32)
         acc = jax.lax.dot_general(
             patch, w_ref[l].reshape(k * k * cu, c).astype(jnp.float32),
             (((1,), (0,)), ((), ())))
-        out = epi.layer_epilogue(
-            acc.reshape(n, oh, ow, c), tlo_ref[l], thi_ref[l], flip_ref[l],
-            const_ref[l], isc_ref[l], pool)         # (N, OH', OW', C) trits
+        z = epi.subsample(acc.reshape(n, h, w, c), stride, (oh, ow))
+        vecs = [r[l:l + 1] for r in (tlo_ref, thi_ref, flip_ref, const_ref,
+                                     isc_ref)]      # (1, C) each
+        out = epi.layer_epilogue(z, *vecs, pool)    # (N, OH', OW', C) trits
         if s_ref is not None:
             stat_rows.append(jnp.stack(
                 [in_zero, epi.zero_count(out), toggle]))
         if l == len(metas) - 1:
             if pack_out:
-                flat = out.reshape(-1)
-                g = o_ref.shape[0]
-                pad = g * TRITS_PER_BYTE - flat.shape[0]
-                o_ref[...] = _pack_trits(jnp.pad(flat, (0, pad)))
+                o_ref[...] = C.pack_rows(out.reshape(-1, c))
             else:
                 o_ref[...] = out
         else:
@@ -185,11 +169,12 @@ def fused_trunk_pallas(x, w_stack, t_lo, t_hi, flip, const, is_const, *,
     trunk-fusibility contract `plan_segments` enforces).
 
     Trit-packed trunk boundaries: with ``packed_in=(N, H, W, Cin)`` the
-    input ``x`` is instead the (G,) uint8 byte stream a ``pack_out=True``
-    trunk produced (5 trits/byte, `repro.core.codec` layout), decoded
-    in-VMEM inside the kernel; with ``pack_out=True`` the result is the
-    packed (G,) byte stream of the final trit map.  Chaining trunks this
-    way means only packed bytes ever cross HBM between them.
+    input ``x`` is instead the (N*H*W, ceil(Cin/5)) uint8 bytes a
+    ``pack_out=True`` trunk produced — each pixel's channels one
+    `repro.core.codec.pack_rows` row, 5 trits/byte — decoded in-VMEM
+    inside the kernel; with ``pack_out=True`` the result is the final
+    trit map packed the same way.  Chaining trunks this way means only
+    packed bytes ever cross HBM between them.
 
     In-kernel switching counters: with ``emit_stats=True`` a second
     (L, 3) int32 output rides along — per layer (input-zero count over
@@ -213,9 +198,9 @@ def fused_trunk_pallas(x, w_stack, t_lo, t_hi, flip, const, is_const, *,
     else:
         n, h, w, cin = packed_in
         assert cin <= cu, (packed_in, cu)
-        assert x.shape == (packed_size(n * h * w * cin),), (
+        assert x.shape == (n * h * w, packed_size(cin)), (
             x.shape, packed_in)
-        in_spec = pl.BlockSpec((x.shape[0],), lambda i: (0,))
+        in_spec = pl.BlockSpec(x.shape, lambda i: (0, 0))
     p = k // 2
     shapes = trunk_shapes((h, w), k, metas)
     oh, ow = shapes[-1]
@@ -227,9 +212,9 @@ def fused_trunk_pallas(x, w_stack, t_lo, t_hi, flip, const, is_const, *,
           jnp.asarray(is_const).astype(jnp.int8).reshape(nl, c)]
 
     if pack_out:
-        g = packed_size(n * oh * ow * c)
-        out_spec = pl.BlockSpec((g,), lambda i: (0,))
-        out_shape = jax.ShapeDtypeStruct((g,), jnp.uint8)
+        rows = (n * oh * ow, packed_size(c))
+        out_spec = pl.BlockSpec(rows, lambda i: (0, 0))
+        out_shape = jax.ShapeDtypeStruct(rows, jnp.uint8)
     else:
         out_spec = pl.BlockSpec((n, oh, ow, c), lambda i: (0, 0, 0, 0))
         out_shape = jax.ShapeDtypeStruct((n, oh, ow, c), jnp.int8)
@@ -260,6 +245,7 @@ def fused_trunk_pallas(x, w_stack, t_lo, t_hi, flip, const, is_const, *,
         out_specs=out_spec,
         out_shape=out_shape,
         scratch_shapes=[scratch, scratch],
-        compiler_params=compiler_params(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x, w_stack.astype(jnp.int8), *th)

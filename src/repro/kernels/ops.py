@@ -27,12 +27,8 @@ def default_backend() -> str:
     env = os.environ.get("REPRO_KERNEL_BACKEND")
     if env:
         return env
-    try:
-        if jax.devices()[0].platform == "tpu":
-            return "pallas"
-    except Exception:
-        pass
-    return "ref"
+    # No fallback: a JAX backend that fails to initialise raises here.
+    return "pallas" if jax.devices()[0].platform == "tpu" else "ref"
 
 
 def _interp(backend: str) -> bool:

@@ -10,7 +10,10 @@ is computed in a single cycle ... no storing of partial results" (§III-C).
 
 The K*K spatial taps are a Python loop *inside* the kernel (fully unrolled
 at trace time — the filter-dimension unrolling of Listing 1), each tap being
-an (OH*OW, C_in) x (C_in, bco) int8 MXU dot.
+an (OH*OW, C_in) x (C_in, bco) int8 MXU dot.  The taps always run at
+stride 1; a strided layer keeps every stride-th accumulator row and
+column (`epilogue.subsample`), because the TPU compiler lowers only
+unit-stride slices.
 
 Layout: x NHWC (pre-padded outside), w HWIO, out NHWC.  The fused epilogue
 (`repro.kernels.epilogue`, shared with the fused-trunk megakernel) applies
@@ -35,50 +38,53 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.codec import TRITS_PER_BYTE
 from repro.kernels import epilogue as epi
 from repro.kernels import trit_codec as C
-from repro.kernels._compat import compiler_params
+
+# lhs (M, Cin) x rhs (Cin, bco), and x rhs (bco, Cin) for packed rows
+_NN = (((1,), (0,)), ((), ()))
+_NT = (((1,), (1,)), ((), ()))
 
 
-def _conv_taps(xv, w_at, k: int, stride, oh: int, ow: int) -> jax.Array:
-    """Unrolled K*K taps over a padded image -> (OH*OW, bco) int32 acc.
+def _conv_taps(xv, w_at, k: int, oh: int, ow: int, dims=_NN) -> jax.Array:
+    """Unrolled K*K stride-1 taps over a padded image -> (OH*OW, bco)
+    int32 acc.
 
     ``xv`` is the (PH, PW, Cin) padded image; ``w_at(kh, kw)`` yields the
-    (Cin, bco) tap weights (dense read or packed-decode slice).
+    tap weights, contracted with the window by ``dims``.
     """
-    sh, sw = stride
     cin = xv.shape[-1]
     acc = None
     for kh in range(k):                             # completely unrolled taps
         for kw in range(k):
-            win = jax.lax.slice(
-                xv, (kh, kw, 0),
-                (kh + sh * (oh - 1) + 1, kw + sw * (ow - 1) + 1, cin),
-                (sh, sw, 1))                        # (OH, OW, Cin)
+            win = xv[kh:kh + oh, kw:kw + ow, :]     # (OH, OW, Cin)
             d = jax.lax.dot_general(
-                win.reshape(oh * ow, cin), w_at(kh, kw),
-                (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+                win.reshape(oh * ow, cin), w_at(kh, kw), dims,
+                preferred_element_type=jnp.int32)
             acc = d if acc is None else acc + d
     return acc
 
 
-def _finish(acc, o_ref, ep_refs, *, oh: int, ow: int, pool,
-            fuse_threshold: bool):
+def _finish(acc, o_ref, ep_refs, *, full_hw, stride, oh: int, ow: int,
+            pool, fuse_threshold: bool):
     """Shared writeback: raw int32, or the fused epilogue to trits.
 
-    Returns the written block so callers can derive in-VMEM statistics
-    from it without re-reading the output ref.
+    ``acc`` is the stride-1 accumulator over ``full_hw``; (oh, ow) are the
+    layer's strided conv dims.  Returns the written block so callers can
+    derive in-VMEM statistics from it without re-reading the output ref.
     """
+    z = epi.subsample(acc.reshape(1, *full_hw, acc.shape[-1]), stride,
+                      (oh, ow))
     if not fuse_threshold:
-        out = acc.reshape(oh, ow, -1)
+        out = z[0]
         o_ref[0] = out
         return out
-    vecs = [r[0] for r in ep_refs]                  # (bco,) each
+    vecs = [r[...] for r in ep_refs]                # (1, bco) each
     t_lo, t_hi, flip = vecs[:3]
     const, is_const = vecs[3:] if len(vecs) == 5 else (None, None)
-    z = acc.reshape(1, oh, ow, acc.shape[-1])
     out = epi.layer_epilogue(z, t_lo, t_hi, flip, const, is_const, pool)
     o_ref[...] = out
     return out
@@ -112,6 +118,11 @@ def _cell_stats(xv, out, s_ref, *, k: int, padding: bool, hw):
     ])
 
 
+def _full_hw(xv, k: int):
+    """Stride-1 conv output dims of a padded (PH, PW, Cin) image."""
+    return xv.shape[0] - k + 1, xv.shape[1] - k + 1
+
+
 def _conv_kernel(x_ref, w_ref, *rest, k: int, stride, oh: int, ow: int,
                  fuse_threshold: bool, pool, emit_stats: bool, padding,
                  stats_hw):
@@ -121,10 +132,11 @@ def _conv_kernel(x_ref, w_ref, *rest, k: int, stride, oh: int, ow: int,
     else:
         o_ref, s_ref = rest[-1], None
         ep_refs = rest[:-1]  # no scratch: accumulator lives in registers
-    acc = _conv_taps(x_ref[0], lambda kh, kw: w_ref[kh, kw], k, stride,
-                     oh, ow)
-    out = _finish(acc, o_ref, ep_refs, oh=oh, ow=ow, pool=pool,
-                  fuse_threshold=fuse_threshold)
+    xv = x_ref[0]
+    full_hw = _full_hw(xv, k)
+    acc = _conv_taps(xv, lambda kh, kw: w_ref[kh, kw], k, *full_hw)
+    out = _finish(acc, o_ref, ep_refs, full_hw=full_hw, stride=stride,
+                  oh=oh, ow=ow, pool=pool, fuse_threshold=fuse_threshold)
     if s_ref is not None:
         _cell_stats(x_ref[0], out, s_ref, k=k, padding=padding,
                     hw=stats_hw)
@@ -140,16 +152,16 @@ def _packed_conv_kernel(x_ref, wp_ref, *rest, k: int, cin: int, stride,
     else:
         o_ref, s_ref = rest[-1], None
         ep_refs = rest[:-1]
-    trits = C.unpack_digits(wp_ref[...])            # (bco, G, 5)
-    w_rows = trits.reshape(trits.shape[0], -1)[:, :k * k * cin]
+    wp = wp_ref[...]                                # (bco, G) bytes
 
-    def w_at(kh, kw):
-        off = (kh * k + kw) * cin
-        return w_rows[:, off:off + cin].astype(jnp.int8).T   # (Cin, bco)
+    def w_at(kh, kw):                               # (bco, Cin) tap rows
+        return C.unpack_rows(wp, (kh * k + kw) * cin, cin).astype(jnp.int8)
 
-    acc = _conv_taps(x_ref[0], w_at, k, stride, oh, ow)
-    out = _finish(acc, o_ref, ep_refs, oh=oh, ow=ow, pool=pool,
-                  fuse_threshold=bool(ep_refs))
+    xv = x_ref[0]
+    full_hw = _full_hw(xv, k)
+    acc = _conv_taps(xv, w_at, k, *full_hw, dims=_NT)
+    out = _finish(acc, o_ref, ep_refs, full_hw=full_hw, stride=stride,
+                  oh=oh, ow=ow, pool=pool, fuse_threshold=bool(ep_refs))
     if s_ref is not None:
         _cell_stats(x_ref[0], out, s_ref, k=k, padding=padding,
                     hw=stats_hw)
@@ -268,7 +280,7 @@ def ternary_conv2d_pallas(x, w, *, stride=(1, 1), padding=True,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x.astype(jnp.int8), w.astype(jnp.int8), *ep)
@@ -324,7 +336,7 @@ def ternary_conv2d_packed_pallas(x, w_packed, *, k: int, cin: int,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x.astype(jnp.int8), w_packed, *ep)

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
+from repro.kernels import epilogue as epi
 
 TRITS_PER_BYTE = 5
 
@@ -75,11 +75,8 @@ def _mm_kernel(x_ref, w_ref, *rest, epilogue: str, acc_dtype, out_dtype):
         acc = acc_ref[...]
         if epilogue == "threshold":
             t_lo, t_hi, flip = (r[...] for r in ep_refs)   # (1, bn) each
-            z = acc.astype(jnp.float32)
-            fl = flip != 0
-            pos = jnp.where(fl, z < t_hi, z > t_hi)
-            neg = jnp.where(fl, z > t_lo, z < t_lo)
-            o_ref[...] = (pos.astype(jnp.int8) - neg.astype(jnp.int8))
+            o_ref[...] = epi.two_threshold(acc, t_lo, t_hi, flip).astype(
+                jnp.int8)
         elif epilogue == "scale":
             (scale,) = ep_refs
             o_ref[...] = (acc.astype(jnp.float32) * scale[...]).astype(out_dtype)
@@ -134,7 +131,7 @@ def ternary_matmul_pallas(x, w_packed, *, scale=None, t_lo=None, t_hi=None,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_packed, *ep)
@@ -172,7 +169,7 @@ def ternary_matmul_dense_pallas(x, w, *, bm: int = 128, bn: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x.astype(jnp.int8), w.astype(jnp.int8))

@@ -27,7 +27,7 @@ since the codec is lossless.  ``packed_collectives=False`` restores the
 dense exchange (for apples-to-apples measurement).
 
 Everything is built on ``shard_map`` over a ``("data", "filter")`` mesh
-through the version shims in :mod:`repro.launch._compat`, so it runs on
+so it runs on
 CPU host-device meshes (``XLA_FLAGS=--xla_force_host_platform_device_
 count=N``) and real accelerator meshes alike.  Sharded execution is
 bit-identical to the single-device backends: batch shards are
@@ -48,10 +48,9 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.core import codec, engine, folding
-from repro.launch import _compat
 
 Array = jax.Array
 
@@ -161,8 +160,8 @@ class MeshSpec:
                 f"mesh {self} needs {self.n_devices} devices but jax sees "
                 f"{avail}; on CPU, set XLA_FLAGS=--xla_force_host_platform_"
                 f"device_count={self.n_devices} before jax initializes")
-        return _compat.make_mesh((self.data, self.filter, self.layer),
-                                 _AXES)
+        return jax.make_mesh((self.data, self.filter, self.layer), _AXES,
+                             axis_types=(AxisType.Auto,) * len(_AXES))
 
     def __str__(self) -> str:
         s = f"{DATA_AXIS}:{self.data},{FILTER_AXIS}:{self.filter}"
@@ -410,7 +409,7 @@ class ShardedExecution:
                     cur = gather(backend.apply(shard, cur, instr))
                 return cur, [{} for _ in instrs]
 
-        fn = _compat.shard_map(
+        fn = jax.shard_map(
             mapped, mesh=self.mesh,
             in_specs=([P(FILTER_AXIS)] * len(self.lowered), P(DATA_AXIS)),
             out_specs=(P(DATA_AXIS), P()),
@@ -599,7 +598,7 @@ class PipelinedExecution:
             out = jax.lax.psum(outbuf, LAYER_AXIS).astype(x.dtype)
             return out.reshape((x.shape[0],) + x.shape[1:]), {}
 
-        fn = _compat.shard_map(
+        fn = jax.shard_map(
             mapped, mesh=self.mesh,
             in_specs=(P(LAYER_AXIS), P(DATA_AXIS)),
             out_specs=(P(DATA_AXIS), P()),

@@ -3,24 +3,25 @@
 `make_production_mesh` is a FUNCTION so importing this module never touches
 jax device state; the dry-run entry point sets
 XLA_FLAGS=--xla_force_host_platform_device_count=512 before first jax init.
-Mesh construction goes through `repro.launch._compat.make_mesh`, which
-papers over the `jax.sharding.AxisType` / `axis_types=` API generations.
 """
 
 from __future__ import annotations
 
-from repro.launch import _compat
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests / small-scale runs)."""
-    return _compat.make_mesh(shape, axes)
+    """Arbitrary mesh (tests / small-scale runs), every axis Auto."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axis_size(mesh) -> int:

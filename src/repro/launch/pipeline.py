@@ -99,10 +99,8 @@ def pipeline_forward_loss(params, batch, cfg: ArchConfig, mesh,
         outs = ys[n_stages - 1:]
         return outs[None]          # (1, M, mb, s, d) -> P('pod') stacks S
 
-    from repro.launch import _compat
-
     with C.manual_axes({"pod"}):
-        outs = _compat.shard_map(
+        outs = jax.shard_map(
             local, mesh=mesh, axis_names={"pod"},
             in_specs=(P("pod"), P()),
             out_specs=P("pod"),

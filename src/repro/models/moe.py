@@ -196,10 +196,8 @@ def _apply_ep(p, x, cfg, mesh):
         y = jax.lax.psum(y, "model")          # row-parallel combine
         return y.reshape(bl, s, d), lb, zl
 
-    from repro.launch import _compat
-
     bspec = P(batch_axes, None, None) if batch_axes else P(None, None, None)
-    y, lb, zl = _compat.shard_map(
+    y, lb, zl = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(bspec, P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),

@@ -13,7 +13,8 @@ Backends:
 
 * ``ref``    — ``lax.conv_general_dilated`` int32 oracle (fast on CPU),
 * ``pallas`` — the weight-stationary Pallas OCU-array kernel
-  (`repro.kernels.ternary_conv2d`); interpret mode off-TPU.  The whole
+  (`repro.kernels.ternary_conv2d`); interpret mode when JAX runs on the
+  CPU (``JAX_PLATFORMS=cpu``), compiled for the chip on a TPU.  The whole
   layer epilogue (pooling, thresholds, constant channels) runs inside the
   kernel, so the int32 accumulator never leaves VMEM — pool layers
   included,
@@ -52,11 +53,12 @@ Array = jax.Array
 
 @functools.lru_cache(maxsize=1)
 def _on_tpu() -> bool:
-    """Probe the default jax platform once; device topology is static."""
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001 — no devices at all
-        return False
+    """Probe the default jax platform once; device topology is static.
+
+    A JAX backend that fails to initialise raises here: a broken chip
+    must not turn silently into interpret mode on the CPU.  CPU runs get
+    interpret mode by selecting the CPU (``JAX_PLATFORMS=cpu``)."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def _finish_layer(z: Array, instr: engine.LayerInstr) -> Array:

@@ -351,9 +351,10 @@ def test_fused_backend_traced_run_matches_ref_stats():
 
 
 def test_trunk_boundary_packed_io_matches_codec():
-    """fused->fused boundaries: the producer's pack_out byte stream is
-    exactly the reference codec's packing of its trit output, and the
-    consumer's in-kernel decode reproduces the dense execution."""
+    """fused->fused boundaries: the producer's pack_out bytes are
+    exactly the reference codec's packing of its trit output (one row
+    per pixel), and the consumer's in-kernel decode reproduces the dense
+    execution."""
     layers = [_layer(k, 8, 8, const_frac=0.2)
               for k in jax.random.split(jax.random.PRNGKey(37), 4)]
     x = _trits(jax.random.PRNGKey(38), (2, 9, 9, 8))
@@ -371,7 +372,7 @@ def test_trunk_boundary_packed_io_matches_codec():
     assert packed.dtype == jnp.uint8
     assert np.array_equal(
         np.asarray(packed),
-        np.asarray(codec.pack_trits(mid_dense.reshape(-1))))
+        np.asarray(codec.pack_rows(mid_dense.reshape(-1, 8))))
     out = call(b, packed, packed_in=tuple(mid_dense.shape))
     assert np.array_equal(_oracle(layers, x), np.asarray(out))
 

@@ -15,12 +15,6 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
-    from repro.launch import _compat
-    if not _compat.HAS_PARTIAL_MANUAL_SHARD_MAP:
-        # legacy shard_map's auto= emulation can't lower ppermute under
-        # SPMD on this jax ("PartitionId instruction is not supported")
-        print("PIPELINE_SKIP")
-        raise SystemExit(0)
     from repro.launch.mesh import make_mesh
     from repro.launch import pipeline
     from repro.models import common as C, transformer as TF
@@ -53,14 +47,10 @@ SCRIPT = textwrap.dedent("""
 
 
 def test_pipeline_matches_plain_forward():
-    import pytest
-
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
     r = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                        capture_output=True, text=True, timeout=900,
                        cwd=os.path.dirname(os.path.dirname(
                            os.path.abspath(__file__))))
-    if "PIPELINE_SKIP" in r.stdout:
-        pytest.skip("no partial-manual shard_map on this jax")
     assert "PIPELINE_OK" in r.stdout, r.stdout + "\n" + r.stderr[-4000:]

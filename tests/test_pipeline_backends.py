@@ -241,3 +241,36 @@ def test_dense_as_conv_derives_from_instance():
                           np.asarray(x @ w.astype(jnp.int32)))
     with pytest.raises(ValueError, match="exceeds OCU buffer"):
         engine.dense_as_conv(jnp.zeros((80, 4)), inst)
+
+
+def test_device_probe_raises_instead_of_falling_back(monkeypatch):
+    """A JAX backend that fails to initialise is an error, not a silent
+    switch to interpret mode on the CPU."""
+    from repro.kernels import ops
+    from repro.pipeline import backends as B
+
+    def broken():
+        raise RuntimeError("backend failed to initialise")
+
+    monkeypatch.setattr(jax, "devices", broken)
+    monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
+    with pytest.raises(RuntimeError, match="failed to initialise"):
+        B._on_tpu.__wrapped__()
+    with pytest.raises(RuntimeError, match="failed to initialise"):
+        ops.default_backend()
+
+
+def test_chip_smoke_refuses_without_a_tpu():
+    """``chip_smoke.py`` under JAX_PLATFORMS=cpu: non-zero exit, a
+    message naming the platform it found, and no result line."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "chip_smoke.py"], cwd=root, env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode != 0
+    assert "'cpu'" in r.stderr, r.stderr[-2000:]
+    assert '"ok"' not in r.stdout

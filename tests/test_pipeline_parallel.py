@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.compiler import trunks
 from repro.core import engine
@@ -70,9 +71,8 @@ def test_meshspec_layer_axis():
 
 
 def test_meshspec_layer_from_mesh(host_devices):
-    from repro.launch import _compat
-
-    mesh = _compat.make_mesh((2, 1, 4), ("data", "filter", "layer"))
+    mesh = jax.make_mesh((2, 1, 4), ("data", "filter", "layer"),
+                         axis_types=(AxisType.Auto,) * 3)
     assert MeshSpec.parse(mesh) == MeshSpec(data=2, layer=4)
 
 

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.core import engine
 from repro.launch.cutie_mesh import MeshSpec, pad_program_for_filter
@@ -77,9 +78,8 @@ def test_meshspec_parse():
 
 
 def test_meshspec_from_mesh(host_devices):
-    from repro.launch import _compat
-
-    mesh = _compat.make_mesh((2, 4), ("data", "filter"))
+    mesh = jax.make_mesh((2, 4), ("data", "filter"),
+                         axis_types=(AxisType.Auto,) * 2)
     assert MeshSpec.parse(mesh) == MeshSpec(2, 4)
 
 
