@@ -248,4 +248,5 @@ def fused_trunk_pallas(x, w_stack, t_lo, t_hi, flip, const, is_const, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="fused_trunk",
     )(x, w_stack.astype(jnp.int8), *th)

@@ -283,6 +283,7 @@ def ternary_conv2d_pallas(x, w, *, stride=(1, 1), padding=True,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="ternary_conv2d",
     )(x.astype(jnp.int8), w.astype(jnp.int8), *ep)
     if emit_stats:
         y, cells = got
@@ -339,6 +340,7 @@ def ternary_conv2d_packed_pallas(x, w_packed, *, k: int, cin: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="ternary_conv2d_packed",
     )(x.astype(jnp.int8), w_packed, *ep)
     if emit_stats:
         y, cells = got

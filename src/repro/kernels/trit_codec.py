@@ -116,6 +116,7 @@ def pack_trits_pallas(t, *, br: int = 256, bg: int = 128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="pack_trits",
     )(t)
 
 
@@ -135,6 +136,7 @@ def unpack_trits_pallas(b, *, br: int = 256, bg: int = 128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="unpack_trits",
     )(b)
 
 
@@ -162,4 +164,5 @@ def thermometer_pallas(x, m: int, *, ternary: bool = True, br: int = 512,
         out_specs=pl.BlockSpec((br, m), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, m), jnp.int8),
         interpret=interpret,
+        name="thermometer",
     )(x.reshape(r, 1).astype(jnp.int32))

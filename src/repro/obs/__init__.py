@@ -18,7 +18,9 @@ Three legs, one subsystem (see also the README's Observability section):
 :class:`Observability` bundles a recorder and a registry; the serving
 engine owns one and hands it to its executors (``Executor.bind_obs``).
 ``NULL`` is the disabled instance components default to, so
-instrumentation costs nothing until an engine turns it on.
+instrumentation writes no JSON events until an engine turns it on; its
+spans still reach an active ``jax.profiler`` session, so standalone
+executors show in a profile.
 """
 
 from __future__ import annotations
@@ -52,5 +54,6 @@ class Observability:
 
 
 #: The no-op sink: components instrument against ``obs = NULL`` until an
-#: engine binds a live instance, so standalone use records nothing.
+#: engine binds a live instance, so standalone use records no JSON events
+#: (its spans still reach an active profiler session).
 NULL = Observability(trace=False)

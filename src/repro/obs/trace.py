@@ -20,6 +20,18 @@ Timestamps are integer microseconds from a monotonic clock captured at
 recorder construction, so traces are replayable and diffable.  The
 buffer is bounded (``max_events``); overflow increments ``dropped``
 instead of growing without bound, matching the engine's windowed stats.
+A disabled recorder returns from every call before it reads the clock
+or builds an event.
+
+:meth:`TraceRecorder.span` has a second output: for as long as the span
+lasts it also holds a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>``, so while a profiler session is active the span lands
+in the profiler's trace, on the same clock as the device's operations,
+whether or not the recorder is enabled.  With the recorder off and no
+session active a span costs one check of the profiler's state.  Such
+spans must nest on the thread that opens them; the per-request tracks
+that begin in one call and end in another (``queued``, ``execute``) use
+:meth:`begin`/:meth:`end` and stay in the JSON buffer only.
 
 :func:`validate_trace` is the CI-side schema check: required fields,
 globally non-decreasing timestamps, B/E spans balanced LIFO per track
@@ -29,10 +41,14 @@ the guarantees a trace viewer needs to render without glitches.
 
 from __future__ import annotations
 
-import contextlib
 import json
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
+
+#: Prefix of the profiler-side name of every span.
+PROFILER_PREFIX = "repro."
 
 
 class TraceRecorder:
@@ -54,8 +70,6 @@ class TraceRecorder:
         return int((self.clock() - self._t0) * 1e6)
 
     def _emit(self, ev: dict) -> None:
-        if not self.enabled:
-            return
         if len(self.events) >= self.max_events:
             self.dropped += 1
             return
@@ -63,6 +77,8 @@ class TraceRecorder:
 
     def begin(self, name: str, *, tid: int = 0, cat: str = "engine",
               ts_us: Optional[int] = None, **args) -> None:
+        if not self.enabled:
+            return
         self._emit({"name": name, "ph": "B", "cat": cat, "pid": self.pid,
                     "tid": tid,
                     "ts": self.now_us() if ts_us is None else ts_us,
@@ -70,22 +86,27 @@ class TraceRecorder:
 
     def end(self, name: str, *, tid: int = 0, cat: str = "engine",
             ts_us: Optional[int] = None, **args) -> None:
+        if not self.enabled:
+            return
         self._emit({"name": name, "ph": "E", "cat": cat, "pid": self.pid,
                     "tid": tid,
                     "ts": self.now_us() if ts_us is None else ts_us,
                     **({"args": args} if args else {})})
 
-    @contextlib.contextmanager
-    def span(self, name: str, *, tid: int = 0, cat: str = "engine", **args):
-        """``with trace.span("execute", tid=uid):`` — balanced B/E pair."""
-        self.begin(name, tid=tid, cat=cat, **args)
-        try:
-            yield self
-        finally:
-            self.end(name, tid=tid, cat=cat)
+    def span(self, name: str, *, tid: int = 0, cat: str = "engine",
+             **args) -> "_Span":
+        """``with trace.span("decode", cat="llm", live=n) as end:`` — a
+        balanced B/E pair on track ``tid`` and a profiler span
+        ``repro.<name>`` on the calling thread.  ``end`` is a dict whose
+        entries become the E event's args (``end["padded"] = 8``)."""
+        if not self.enabled and not TraceAnnotation.is_enabled():
+            return _NO_SPAN
+        return _Span(self, name, tid, cat, args)
 
     def instant(self, name: str, *, tid: int = 0, cat: str = "engine",
                 **args) -> None:
+        if not self.enabled:
+            return
         self._emit({"name": name, "ph": "i", "s": "t", "cat": cat,
                     "pid": self.pid, "tid": tid, "ts": self.now_us(),
                     **({"args": args} if args else {})})
@@ -93,6 +114,8 @@ class TraceRecorder:
     def counter(self, name: str, values: dict, *, tid: int = 0,
                 cat: str = "engine") -> None:
         """A Perfetto counter-track sample (``values`` are the series)."""
+        if not self.enabled:
+            return
         self._emit({"name": name, "ph": "C", "cat": cat, "pid": self.pid,
                     "tid": tid, "ts": self.now_us(),
                     "args": {k: float(v) for k, v in values.items()}})
@@ -100,6 +123,8 @@ class TraceRecorder:
     def thread_name(self, tid: int, name: str) -> None:
         """Label a track (metadata event; Perfetto shows it as the row
         title instead of a bare tid)."""
+        if not self.enabled:
+            return
         self._emit({"name": "thread_name", "ph": "M", "pid": self.pid,
                     "tid": tid, "ts": 0, "args": {"name": name}})
 
@@ -114,6 +139,44 @@ class TraceRecorder:
             with open(path, "w") as f:
                 json.dump(trace, f)
         return trace
+
+
+class _Span:
+    """The context manager :meth:`TraceRecorder.span` returns: a
+    profiler annotation always, B/E events when the recorder is on."""
+
+    __slots__ = ("rec", "name", "tid", "cat", "args", "end_args", "_ann")
+
+    def __init__(self, rec: TraceRecorder, name: str, tid: int, cat: str,
+                 args: dict):
+        self.rec, self.name, self.tid, self.cat = rec, name, tid, cat
+        self.args = args
+        self.end_args: dict = {}
+        self._ann = TraceAnnotation(PROFILER_PREFIX + name)
+
+    def __enter__(self) -> dict:
+        self._ann.__enter__()
+        self.rec.begin(self.name, tid=self.tid, cat=self.cat, **self.args)
+        return self.end_args
+
+    def __exit__(self, *exc) -> None:
+        self.rec.end(self.name, tid=self.tid, cat=self.cat, **self.end_args)
+        self._ann.__exit__(*exc)
+
+
+class _NoSpan:
+    """What a span is when nothing would record it."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> dict:
+        return {}
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
 
 
 _REQUIRED = ("name", "ph", "pid", "tid", "ts")

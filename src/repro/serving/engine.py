@@ -167,7 +167,7 @@ class CutieEngine:
             self._fallbacks[name] = fallback
         self._quarantined.pop(name, None)
         self._consec[name] = 0
-        executor.bind_obs(self.obs)
+        executor.bind_obs(self.obs, model=name)
         # keyed per model name: hot-swapping replaces the collector
         # instead of leaking the predecessor's callback
         self.obs.metrics.collect(f"executor:{name}",
@@ -201,10 +201,6 @@ class CutieEngine:
         m.gauge("requests_running", "requests admitted, not yet done").set(
             sum(1 for r in self._requests.values()
                 if r.status is RequestStatus.RUNNING))
-        if self._energy_seen:
-            m.gauge("energy_uj_total", "cumulative per-request switching "
-                    "energy priced by tracing executors").set(
-                self._energy_uj)
         m.gauge("retry_queue_depth",
                 "failed batches awaiting backoff retry").set(
             sum(len(reqs) for _, _, reqs in self._retry))
@@ -242,81 +238,85 @@ class CutieEngine:
         *here* — at the caller — instead of letting a doomed request
         consume queue and batch capacity.
         """
-        if model is None:
-            names = self.registry.names()
-            if len(names) == 1:
-                model = names[0]
-            elif "default" in names:
-                model = "default"
-            else:
-                raise ValueError(
-                    "model= is required: engine serves "
-                    f"{names or 'no models'}")
-        if model not in self.registry:
-            self.registry[model]      # raises the canonical unknown-model
-        if model in self._quarantined:
-            fb = self._usable_fallback(model)
-            if fb is None:
-                raise ModelQuarantinedError(
-                    f"model {model!r} is quarantined after "
-                    f"{self._consec.get(model, 0)} consecutive executor "
-                    "failures and has no healthy fallback; hot-swap it "
-                    "or call reinstate()")
-            self.n_rerouted += 1
-            self.obs.metrics.counter(
-                "requests_rerouted_total", "submissions rerouted to a "
-                "fallback model during quarantine").inc(
-                model=model, fallback=fb)
-            model = fb
-        pol = self.policy
-        depth = len(self.scheduler)
-        if pol.max_queue_depth is not None and depth >= pol.max_queue_depth:
-            self._count_shed(model, "queue_depth")
-            raise LoadShedError(
-                f"queue depth {depth} at max_queue_depth="
-                f"{pol.max_queue_depth}; retry later")
-        executor = self.registry[model]
-        if pol.shed_on_deadline and deadline is not None:
-            est = self._estimated_wait(model, executor, depth)
-            if est is not None and est > deadline:
-                self._count_shed(model, "deadline")
+        with self.obs.trace.span("submit"):
+            if model is None:
+                names = self.registry.names()
+                if len(names) == 1:
+                    model = names[0]
+                elif "default" in names:
+                    model = "default"
+                else:
+                    raise ValueError(
+                        "model= is required: engine serves "
+                        f"{names or 'no models'}")
+            if model not in self.registry:
+                self.registry[model]      # raises the canonical unknown-model
+            if model in self._quarantined:
+                fb = self._usable_fallback(model)
+                if fb is None:
+                    raise ModelQuarantinedError(
+                        f"model {model!r} is quarantined after "
+                        f"{self._consec.get(model, 0)} consecutive executor "
+                        "failures and has no healthy fallback; hot-swap it "
+                        "or call reinstate()")
+                self.n_rerouted += 1
+                self.obs.metrics.counter(
+                    "requests_rerouted_total", "submissions rerouted to a "
+                    "fallback model during quarantine").inc(
+                    model=model, fallback=fb)
+                model = fb
+            pol = self.policy
+            depth = len(self.scheduler)
+            if pol.max_queue_depth is not None \
+                    and depth >= pol.max_queue_depth:
+                self._count_shed(model, "queue_depth")
                 raise LoadShedError(
-                    f"deadline {deadline:.3f}s cannot be met: estimated "
-                    f"wait {est:.3f}s at queue depth {depth}")
-        if pol.pressure_queue_depth is not None \
-                and depth >= pol.pressure_queue_depth \
-                and getattr(executor, "spec", None) is not None \
-                and spec_k != 0:
-            # graceful degradation: give up speculative speedup (extra
-            # decode work per token) before giving up admission
-            spec_k = 0
-            self.n_degraded += 1
+                    f"queue depth {depth} at max_queue_depth="
+                    f"{pol.max_queue_depth}; retry later")
+            executor = self.registry[model]
+            if pol.shed_on_deadline and deadline is not None:
+                est = self._estimated_wait(model, executor, depth)
+                if est is not None and est > deadline:
+                    self._count_shed(model, "deadline")
+                    raise LoadShedError(
+                        f"deadline {deadline:.3f}s cannot be met: estimated "
+                        f"wait {est:.3f}s at queue depth {depth}")
+            if pol.pressure_queue_depth is not None \
+                    and depth >= pol.pressure_queue_depth \
+                    and getattr(executor, "spec", None) is not None \
+                    and spec_k != 0:
+                # graceful degradation: give up speculative speedup (extra
+                # decode work per token) before giving up admission
+                spec_k = 0
+                self.n_degraded += 1
+                self.obs.metrics.counter(
+                    "requests_degraded_total", "requests admitted with "
+                    "speculation disabled under queue pressure").inc(
+                    model=model)
+            with self.obs.trace.span("validate"):
+                value = executor.validate(value)
+            self._uid += 1
+            self._seq += 1
+            req = Request(uid=self._uid, model=model, value=value,
+                          priority=priority, deadline=deadline, tag=tag,
+                          spec_k=spec_k, timeout=timeout, seq=self._seq,
+                          submit_t=self.clock())
+            self.scheduler.add(req)
+            handle = RequestHandle(self, req)
+            self._requests[req.uid] = req
+            self._handles[req.uid] = handle
+            if timeout is not None:
+                self._timed.add(req.uid)
             self.obs.metrics.counter(
-                "requests_degraded_total", "requests admitted with "
-                "speculation disabled under queue pressure").inc(
-                model=model)
-        value = executor.validate(value)
-        self._uid += 1
-        self._seq += 1
-        req = Request(uid=self._uid, model=model, value=value,
-                      priority=priority, deadline=deadline, tag=tag,
-                      spec_k=spec_k, timeout=timeout, seq=self._seq,
-                      submit_t=self.clock())
-        self.scheduler.add(req)
-        handle = RequestHandle(self, req)
-        self._requests[req.uid] = req
-        self._handles[req.uid] = handle
-        if timeout is not None:
-            self._timed.add(req.uid)
-        self.obs.metrics.counter(
-            "requests_submitted_total",
-            "requests accepted by submit()").inc(model=model)
-        if self.obs.enabled:
-            self.obs.trace.thread_name(req.uid, f"req {req.uid} ({model})")
-            self.obs.trace.instant("submit", tid=req.uid, cat="request",
-                                   model=model)
-            self.obs.trace.begin("queued", tid=req.uid, cat="request")
-        return handle
+                "requests_submitted_total",
+                "requests accepted by submit()").inc(model=model)
+            if self.obs.enabled:
+                self.obs.trace.thread_name(req.uid,
+                                           f"req {req.uid} ({model})")
+                self.obs.trace.instant("submit", tid=req.uid, cat="request",
+                                       model=model)
+                self.obs.trace.begin("queued", tid=req.uid, cat="request")
+            return handle
 
     def _count_shed(self, model: str, reason: str) -> None:
         self.n_shed += 1
@@ -368,34 +368,35 @@ class CutieEngine:
         the handle (``result()`` raises ``req.error``), and co-batched
         innocents keep running.
         """
-        now = self.clock()
-        self._queue_depth.append(len(self.scheduler))
-        self._expire(now)
-        self._maybe_reinstate(now)
-        progressed = self._run_due_retries(now)
-        capacities = {name: (0 if name in self._quarantined
-                             else ex.free_capacity())
-                      for name, ex in self.registry.items()}
-        with self.obs.trace.span("schedule", tid=0, cat="engine",
-                                 queued=len(self.scheduler)):
-            picked = self.scheduler.next_batch(capacities, now)
-        admissions = {picked[0]: picked[1]} if picked else {}
-        for name, executor in self.registry.items():
-            if name in self._quarantined:
-                continue
-            reqs = admissions.get(name, [])
-            if not reqs and not executor.has_resident():
-                continue
-            self._run_batch(name, executor, reqs)
-            progressed = True
-        if not progressed and self._retry:
-            # only future retries remain: sleep to the earliest one so
-            # backoff never reads as a dead engine to run()/result()
-            delay = min(at for at, _, _ in self._retry) - self.clock()
-            if delay > 0:
-                self.sleep(delay)
-            return True
-        return progressed
+        with self.obs.trace.span("step"):
+            now = self.clock()
+            self._queue_depth.append(len(self.scheduler))
+            self._expire(now)
+            self._maybe_reinstate(now)
+            progressed = self._run_due_retries(now)
+            capacities = {name: (0 if name in self._quarantined
+                                 else ex.free_capacity())
+                          for name, ex in self.registry.items()}
+            with self.obs.trace.span("schedule", tid=0, cat="engine",
+                                     queued=len(self.scheduler)):
+                picked = self.scheduler.next_batch(capacities, now)
+            admissions = {picked[0]: picked[1]} if picked else {}
+            for name, executor in self.registry.items():
+                if name in self._quarantined:
+                    continue
+                reqs = admissions.get(name, [])
+                if not reqs and not executor.has_resident():
+                    continue
+                self._run_batch(name, executor, reqs)
+                progressed = True
+            if not progressed and self._retry:
+                # only future retries remain: sleep to the earliest one so
+                # backoff never reads as a dead engine to run()/result()
+                delay = min(at for at, _, _ in self._retry) - self.clock()
+                if delay > 0:
+                    self.sleep(delay)
+                return True
+            return progressed
 
     def _run_due_retries(self, now: float) -> bool:
         """Execute retry batches whose backoff elapsed.  They bypass the
@@ -452,18 +453,20 @@ class CutieEngine:
                     "queue_time_seconds",
                     "submit-to-admission wait per request").observe(
                     r.queue_time, model=name)
-        self.obs.trace.begin("batch", tid=0, cat="engine", model=name,
-                             live=len(reqs))
-        try:
-            report = executor.execute(reqs)
-        except Exception as err:
-            self.obs.trace.end("batch", tid=0, cat="engine",
-                               error=repr(err))
-            self._on_failure(name, executor, reqs, err)
+        failure = None
+        with self.obs.trace.span("batch", model=name,
+                                 live=len(reqs)) as end:
+            try:
+                report = executor.execute(reqs)
+            except Exception as err:
+                failure = err
+                end["error"] = repr(err)
+            else:
+                done_t = self.clock()
+                end.update(live=report.live, padded=report.padded)
+        if failure is not None:
+            self._on_failure(name, executor, reqs, failure)
             return
-        done_t = self.clock()
-        self.obs.trace.end("batch", tid=0, cat="engine",
-                           live=report.live, padded=report.padded)
         self._consec[name] = 0
         self.n_batches += 1
         self.batches.append({

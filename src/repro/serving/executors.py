@@ -52,14 +52,18 @@ class Executor:
     ``obs`` is the observability sink (`repro.obs.Observability`) the
     executor emits trace spans and metrics into; it defaults to the
     module-level no-op `repro.obs.NULL`, and the serving engine rebinds
-    it (``bind_obs``) at registration so standalone executors cost
-    nothing while engine-owned ones share the engine's recorder.
+    it (``bind_obs``) at registration so standalone executors write no
+    trace events while engine-owned ones share the engine's recorder.
+    ``model`` is the name the engine registered the executor under, the
+    ``model`` label of the metrics it publishes itself.
     """
 
     obs = _obs.NULL
+    model = ""
 
-    def bind_obs(self, obs) -> None:
+    def bind_obs(self, obs, model: str = "") -> None:
         self.obs = obs
+        self.model = model
 
     def validate(self, value):
         """Canonicalize one submitted input; raise on bad requests.
@@ -194,11 +198,16 @@ class ProgramExecutor(Executor):
             # hot-swapped in with traffic already queued: the requests
             # were validated by the predecessor, so lock to their shape
             self._shape = tuple(requests[0].value.shape)
-        batch = np.zeros((size,) + self._shape, np.int8)
-        for i, req in enumerate(requests):
-            batch[i] = req.value
+        trace = self.obs.trace
+        with trace.span("pad", live=live, padded=size):
+            batch = np.zeros((size,) + self._shape, np.int8)
+            for i, req in enumerate(requests):
+                batch[i] = req.value
+        with trace.span("put"):
+            batch = jnp.asarray(batch)
         variants_before = self.pipeline.n_jit_variants
-        out = self.pipeline.run(jnp.asarray(batch), tracer=self.tracer)
+        with trace.span("run"):
+            out = self.pipeline.run(batch, tracer=self.tracer)
         if self.pipeline.n_jit_variants > variants_before:
             # a fresh jit specialization compiled inside this batch —
             # the latency outlier a trace should be able to explain
@@ -211,11 +220,13 @@ class ProgramExecutor(Executor):
         rows = None
         if self.tracer is not None:
             out, rows = out
-        feats = np.asarray(out)[:live]
-        completions = [
-            (req.uid, self.head(feats[i]) if self.head is not None
-             else feats[i])
-            for i, req in enumerate(requests)]
+        with trace.span("readback"):
+            feats = np.asarray(out)[:live]
+        with trace.span("head"):
+            completions = [
+                (req.uid, self.head(feats[i]) if self.head is not None
+                 else feats[i])
+                for i, req in enumerate(requests)]
         return ExecutionReport(completions, live, size, rows=rows,
                                energy_uj=self._price(rows),
                                per_device_live=self._per_device_live(live,

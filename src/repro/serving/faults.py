@@ -227,9 +227,9 @@ class FaultyExecutor(Executor):
 
     # -- delegation ---------------------------------------------------------
 
-    def bind_obs(self, obs) -> None:
-        self.obs = obs
-        self.inner.bind_obs(obs)
+    def bind_obs(self, obs, model: str = "") -> None:
+        super().bind_obs(obs, model)
+        self.inner.bind_obs(obs, model)
 
     def validate(self, value):
         return self.inner.validate(value)

@@ -217,11 +217,21 @@ class LLMExecutor(Executor):
         if live == 0:
             return ExecutionReport(completions, 0, self.scfg.n_slots,
                                    tokens_generated={})
-        with self.obs.trace.span("decode", tid=0, cat="llm", live=live):
+        trace = self.obs.trace
+        with trace.span("decode", cat="llm", live=live):
             step_tokens = self._step_tokens()
-        self.obs.trace.counter("blocks", {
+        trace.counter("blocks", {
             "active": self.pool.n_active, "cached": self.pool.n_cached,
             "free": self.pool.n_free})
+        with trace.span("emit", cat="llm"):
+            tokens_generated = self._emit(step_tokens, completions)
+        return ExecutionReport(completions, live, self.scfg.n_slots,
+                               tokens_generated=tokens_generated)
+
+    def _emit(self, step_tokens: dict, completions: list) -> dict:
+        """Append each live slot's new tokens, finish and release the
+        slots that stopped (appending to ``completions``); returns the
+        tokens emitted per request."""
         tokens_generated: dict[int, int] = {}
         for i, req in enumerate(self.slots):
             if req is None:
@@ -247,16 +257,25 @@ class LLMExecutor(Executor):
             if finished:
                 completions.append((req.uid, self._tokens.pop(req.uid)))
                 self._release(i)
-        return ExecutionReport(completions, live, self.scfg.n_slots,
-                               tokens_generated=tokens_generated)
+        return tokens_generated
 
     def _step_tokens(self) -> dict[int, list[int]]:
         """One engine step's new tokens per live slot.  The base decode
         loop emits exactly one; `SpecExecutor` overrides this with the
         propose/verify/accept cycle (1 .. k+1 tokens per slot)."""
         nxt = self.decode()
-        return {i: [int(nxt[i])]
-                for i, r in enumerate(self.slots) if r is not None}
+        with self.obs.trace.span("sync", cat="llm"):
+            out = {i: [int(nxt[i])]
+                   for i, r in enumerate(self.slots) if r is not None}
+        self._count_reads("sync", len(out))
+        return out
+
+    def _count_reads(self, site: str, n: int = 1) -> None:
+        """Count ``n`` blocking device-to-host reads made at ``site``."""
+        self.obs.metrics.counter(
+            "device_reads_total", "blocking device-to-host reads made "
+            "by executors on the host path, by site").inc(
+            n, model=self.model, site=site)
 
     def extra_stats(self) -> dict:
         """Paged-state accounting for ``engine.stats()``."""
@@ -287,18 +306,17 @@ class LLMExecutor(Executor):
         plen = len(tokens)
         self._prompts[uid] = np.asarray(tokens, np.int64)
         self.prefill_tokens += plen
-        self.obs.trace.begin("prefill", tid=uid, cat="request",
-                             prompt_len=plen)
-        if self.is_ssm:
-            res = self._prefill_ssm(uid, slot, tokens)
-        elif self.scfg.paged:
-            res = self._prefill_paged(uid, slot, tokens)
-        else:
-            res = self._prefill_contiguous(uid, slot, tokens)
+        with self.obs.trace.span("prefill", tid=uid, cat="request",
+                                 prompt_len=plen) as end:
+            if self.is_ssm:
+                res = self._prefill_ssm(uid, slot, tokens)
+            elif self.scfg.paged:
+                res = self._prefill_paged(uid, slot, tokens)
+            else:
+                res = self._prefill_contiguous(uid, slot, tokens)
+            cached = res.prefix.common_prefix_tokens
+            end.update(cached=cached, computed=res.tokens_computed)
         self.prefill_tokens_computed += res.tokens_computed
-        cached = res.prefix.common_prefix_tokens
-        self.obs.trace.end("prefill", tid=uid, cat="request",
-                           cached=cached, computed=res.tokens_computed)
         self.obs.trace.instant("prefix_hit" if cached else "prefix_miss",
                                tid=uid, cat="prefix", tokens=cached)
         self.obs.metrics.counter(
@@ -354,7 +372,7 @@ class LLMExecutor(Executor):
             jnp.int32(n_real), {n: kv[n][:, 0] for n in ("k", "v")})
         if scfg.prefix_caching:
             self.manager.commit(uid)
-        first = int(self._sample(last_logits[None])[0])
+        first = self._first_token(last_logits)
         self._tokens[uid] = [first]
         self.slots[slot] = _Resident(uid)
         return PrefillResult(first, ExistingPrefix(c, tuple(
@@ -370,7 +388,7 @@ class LLMExecutor(Executor):
             n: self.caches["kv"][n].at[:, slot, :plen].set(
                 kv[n][:, 0, :plen].astype(self.caches["kv"][n].dtype))
             for n in ("k", "v")}
-        first = int(self._sample(last_logits[None])[0])
+        first = self._first_token(last_logits)
         self._tokens[uid] = [first]
         self.slots[slot] = _Resident(uid)
         return PrefillResult(first, ExistingPrefix(0, ()), plen, n_real)
@@ -423,15 +441,22 @@ class LLMExecutor(Executor):
         n_real = plen - c
         if self.scfg.paged:
             self.state_store.write_(int(self._slot_bids[slot]), state)
+            self._count_reads("prefill")
         else:
             self.caches["ssm"] = jax.tree.map(
                 lambda full, one: full.at[:, slot].set(one),
                 self.caches["ssm"], state)
-        first = int(self._sample(logits[0, -1][None])[0])
+        first = self._first_token(logits[0, -1])
         self._tokens[uid] = [first]
         self.slots[slot] = _Resident(uid)
         return PrefillResult(first, ExistingPrefix(c, hit_blocks),
                              plen, n_real)
+
+    def _first_token(self, last_logits) -> int:
+        """Sample a prompt's first output token and read it back."""
+        first = int(self._sample(last_logits[None])[0])
+        self._count_reads("prefill")
+        return first
 
     def _commit_snapshot(self, h: str, state) -> None:
         """Park one boundary snapshot in the cache; skip when the pool
@@ -450,38 +475,48 @@ class LLMExecutor(Executor):
     def decode(self) -> jax.Array:
         """One jitted decode step for every slot; returns the sampled
         next token per slot (junk rows for empty slots)."""
+        trace = self.obs.trace
         if not self.scfg.paged:
-            logits, self.caches = self._decode_fn(
-                self.params, self.cur_tok, self.caches, self.pos)
+            with trace.span("dispatch", cat="llm"):
+                logits, self.caches = self._decode_fn(
+                    self.params, self.cur_tok, self.caches, self.pos)
         elif self.is_ssm:
-            logits, self.state_store.pages = self._decode_fn(
-                self.params, self.cur_tok, self.state_store.pages,
-                self._slot_bids, self.pos)
+            with trace.span("dispatch", cat="llm"):
+                logits, self.state_store.pages = self._decode_fn(
+                    self.params, self.cur_tok, self.state_store.pages,
+                    self._slot_bids, self.pos)
         else:
-            self._cow_for_decode()
-            tables = np.stack([
-                self.manager.table_array(r.uid, self.blocks_per_seq)
-                if r is not None else
-                np.zeros((self.blocks_per_seq,), np.int32)
-                for r in self.slots])
-            logits, self.kv_store.pages = self._decode_fn(
-                self.params, self.cur_tok, self.kv_store.pages,
-                jnp.asarray(tables), self.pos)
-        nxt = self._sample(logits[:, -1])
-        self.pos = self.pos + 1
-        self.cur_tok = nxt[:, None]
+            with trace.span("cow", cat="llm"):
+                self._cow_for_decode()
+            with trace.span("tables", cat="llm"):
+                tables = jnp.asarray(np.stack([
+                    self.manager.table_array(r.uid, self.blocks_per_seq)
+                    if r is not None else
+                    np.zeros((self.blocks_per_seq,), np.int32)
+                    for r in self.slots]))
+            with trace.span("dispatch", cat="llm"):
+                logits, self.kv_store.pages = self._decode_fn(
+                    self.params, self.cur_tok, self.kv_store.pages,
+                    tables, self.pos)
+        with trace.span("sample", cat="llm"):
+            nxt = self._sample(logits[:, -1])
+            self.pos = self.pos + 1
+            self.cur_tok = nxt[:, None]
         return nxt
 
     def _cow_for_decode(self) -> None:
         """Make every live slot's write-target block exclusively owned
         (fires only after forks / prefix sharing into the write block)."""
         pairs = []
+        n_reads = 0
         for i, r in enumerate(self.slots):
             if r is None:
                 continue
             pair = self.manager.ensure_writable(r.uid, int(self.pos[i]))
+            n_reads += 1
             if pair is not None:
                 pairs.append(pair)
+        self._count_reads("cow", n_reads)
         self.kv_store.apply_copies(pairs)
 
     # -- engine failure paths ------------------------------------------------

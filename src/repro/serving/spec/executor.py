@@ -158,6 +158,7 @@ class SpecExecutor(LLMExecutor):
         k = min(self._adaptive.k(),
                 self.scfg.max_new_tokens - m - 1,       # emit <= k+1 more
                 self.scfg.max_len - 1 - int(self.pos[slot]))
+        self._count_reads("cow")
         if cap is not None:
             k = min(k, cap)
         return max(k, 0)
@@ -168,6 +169,7 @@ class SpecExecutor(LLMExecutor):
         committed = np.concatenate(
             [self._prompts[uid], np.asarray(toks[:-1], np.int64)])
         pos = int(self.pos[slot])
+        self._count_reads("cow")
         full = np.concatenate([committed, [cur]])
 
         with self.obs.trace.span("spec_propose", tid=uid, cat="spec", k=k):
@@ -232,6 +234,7 @@ class SpecExecutor(LLMExecutor):
                     self.slots[i].uid, int(self.pos[i]))
                 if pair is not None:
                     pairs.append(pair)
+            self._count_reads("cow", len(subset))
             self.kv_store.apply_copies(pairs)
             tables = np.stack([
                 self.manager.table_array(self.slots[i].uid,
@@ -245,6 +248,7 @@ class SpecExecutor(LLMExecutor):
         self.pos = jnp.where(maskj, self.pos + 1, self.pos)
         self.cur_tok = jnp.where(maskj[:, None], nxt[:, None],
                                  self.cur_tok)
+        self._count_reads("sync")
         return np.asarray(nxt)
 
     # -- stats ---------------------------------------------------------------
