@@ -102,8 +102,6 @@ class Executor:
 
 DEFAULT_BUCKETS = (1, 2, 4, 8)
 
-_TRITS = (-1, 0, 1)
-
 
 class ProgramExecutor(Executor):
     """Bucketed whole-program executor over a `CutiePipeline`.
@@ -165,7 +163,11 @@ class ProgramExecutor(Executor):
     def validate(self, value) -> np.ndarray:
         """Trit-domain validation: (H, W, C), values in {-1, 0, +1},
         int8-coercible — rejected with a clear error, never silently cast."""
-        arr = np.asarray(value)
+        # copy first: the caller's array is read once, in its own memory
+        # order, into a compact private copy that every check then reads (a
+        # view strided across a larger array costs a gather on each pass),
+        # and the caller cannot change what is served
+        arr = np.array(value)
         if arr.ndim != 3:
             raise ValueError(f"expected (H, W, C) trit image, "
                              f"got {arr.shape}")
@@ -182,12 +184,13 @@ class ProgramExecutor(Executor):
             raise ValueError(
                 "trit image is not int8-coercible: non-integral float "
                 "values (quantize to {-1, 0, +1} before submitting)")
-        ok = np.isin(arr, _TRITS)
-        if not ok.all():
-            bad = np.unique(np.asarray(arr)[~ok])[:5]
+        # an integral value lies in {-1, 0, +1} exactly when it lies in
+        # [-1, 1]: two reductions decide it, with no per-element mask
+        if arr.size and not (arr.min() >= -1 and arr.max() <= 1):
+            bad = np.unique(arr[(arr < -1) | (arr > 1)])[:5]
             raise ValueError(f"trit image values must be in "
                              f"{{-1, 0, +1}}, got {bad.tolist()}")
-        return arr.astype(np.int8)
+        return arr.astype(np.int8, copy=False)
 
     def execute(self, requests) -> ExecutionReport:
         import jax.numpy as jnp

@@ -263,6 +263,86 @@ def test_submit_rejects_out_of_domain_trits():
     assert eng.submit(np.ones((8, 8, 8), bool)).result() is not None
 
 
+def _trits_of(dtype, values=(-1, 0, 1)):
+    return np.resize(np.asarray(values, dtype), (8, 8, 8))
+
+
+def _bad_last(dtype, value):
+    arr = np.zeros((8, 8, 8), dtype)
+    arr[-1, -1, -1] = value
+    return arr
+
+
+@pytest.mark.parametrize("make", [
+    lambda: _trits_of(np.int8),
+    lambda: _trits_of(np.int16),
+    lambda: _trits_of(np.int64),
+    lambda: _trits_of(np.uint8, (0, 1)),
+    lambda: _trits_of(bool, (False, True)),
+    lambda: _trits_of(np.float32, (-1.0, 0.0, 1.0, -0.0)),
+    lambda: _trits_of(np.float64, (-1.0, 0.0, 1.0, -0.0)),
+    lambda: np.zeros((8, 8, 0), np.int8),
+    lambda: np.asfortranarray(np.resize(_trits_of(np.int8), (4, 8, 8, 8)))[2],
+], ids=["int8", "int16", "int64", "uint8", "bool", "float32", "float64",
+        "empty", "strided_view"])
+def test_validate_accepts_trits_as_fresh_int8(make):
+    arr = make()
+    out = ProgramExecutor(_pipe()).validate(arr)
+    assert out.dtype == np.int8
+    np.testing.assert_array_equal(out, arr)
+    assert not np.shares_memory(out, arr)
+
+
+_DOMAIN = r"values must be in \{-1, 0, \+1\}, got "
+_COERCE = "not int8-coercible: non-integral float"
+
+
+@pytest.mark.parametrize("make, exc, match", [
+    (lambda: np.full((8, 8, 8), 2, np.int64), ValueError,
+     _DOMAIN + r"\[2\]"),
+    (lambda: np.full((8, 8, 8), -2, np.int64), ValueError,
+     _DOMAIN + r"\[-2\]"),
+    (lambda: np.full((8, 8, 8), -128, np.int8), ValueError,
+     _DOMAIN + r"\[-128\]"),
+    (lambda: np.full((8, 8, 8), 255, np.uint8), ValueError,
+     _DOMAIN + r"\[255\]"),
+    (lambda: _trits_of(np.int64, range(-7, 8)), ValueError,
+     _DOMAIN + r"\[-7, -6, -5, -4, -3\]$"),
+    (lambda: np.full((8, 8, 8), 0.5), ValueError, _COERCE),
+    (lambda: np.full((8, 8, 8), np.nan), ValueError, _COERCE),
+    (lambda: np.full((8, 8, 8), np.inf), ValueError, _COERCE),
+    (lambda: np.full((8, 8, 8), -np.inf), ValueError, _COERCE),
+    (lambda: np.full((8, 8, 8), 2.0), ValueError, _DOMAIN + r"\[2\.0\]"),
+    (lambda: np.full((8, 8, 8), "x"), TypeError, "must be numeric"),
+    (lambda: np.zeros((8, 8, 8), np.complex64), TypeError,
+     "must be numeric"),
+    (lambda: np.zeros((8, 8, 8), object), TypeError, "must be numeric"),
+    (lambda: np.zeros((8, 8), np.int8), ValueError, r"expected \(H, W, C\)"),
+    (lambda: np.zeros((1, 8, 8, 8), np.int8), ValueError,
+     r"expected \(H, W, C\)"),
+    (lambda: _bad_last(np.int8, 2), ValueError, _DOMAIN + r"\[2\]"),
+    (lambda: _bad_last(np.float32, -3.0), ValueError,
+     _DOMAIN + r"\[-3\.0\]"),
+    (lambda: _bad_last(np.float64, 0.5), ValueError, _COERCE),
+], ids=["int64_2", "int64_-2", "int8_-128", "uint8_255", "five_listed",
+        "half", "nan", "+inf", "-inf", "float_2", "str", "complex",
+        "object", "2d", "4d", "int8_last", "float_last", "half_last"])
+def test_validate_rejects_non_trits(make, exc, match):
+    with pytest.raises(exc, match=match):
+        ProgramExecutor(_pipe()).validate(make())
+
+
+def test_mutating_a_submitted_image_leaves_its_answer():
+    eng = _pipe().engine()
+    img = _img(np.random.default_rng(3))
+    want = eng.submit(img.copy()).result()
+    flipped = eng.submit(-img).result()
+    assert not np.array_equal(flipped, want)
+    req = eng.submit(img)
+    img *= -1
+    np.testing.assert_array_equal(req.result(), want)
+
+
 def test_submit_locks_serving_shape():
     eng = _pipe().engine()
     eng.submit(np.zeros((8, 8, 8), np.int8))
