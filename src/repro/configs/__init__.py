@@ -18,6 +18,7 @@ ARCH_IDS = [
     "qwen3_moe_30b_a3b",
     "zamba2_2_7b",
     "llava_next_mistral_7b",
+    "moonlight_16b_a3b",
 ]
 
 ALIASES = {a.replace("_", "-"): a for a in ARCH_IDS}
@@ -32,6 +33,7 @@ ALIASES.update({
     "qwen3-moe-30b-a3b": "qwen3_moe_30b_a3b",
     "zamba2-2.7b": "zamba2_2_7b",
     "llava-next-mistral-7b": "llava_next_mistral_7b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 })
 
 

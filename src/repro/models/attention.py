@@ -91,7 +91,7 @@ def flash_attention(q, k, v, *, causal: bool, q_chunk: int, kv_chunk: int,
     the 16-way head sharding at the reshape.
     """
     b, s, h, d = q.shape
-    t = k.shape[1]
+    t, dv = k.shape[1], v.shape[-1]          # dv may differ from d (MLA)
     assert k.shape[2] == h, (q.shape, k.shape)
     cq = min(q_chunk, s)
     ck = min(kv_chunk, t)
@@ -111,7 +111,7 @@ def flash_attention(q, k, v, *, causal: bool, q_chunk: int, kv_chunk: int,
 
     qc = jnp.moveaxis(q.reshape(b, nq, cq, h, d), 1, 0)
     kc = jnp.moveaxis(k.reshape(b, nk, ck, h, d), 1, 0)
-    vc = jnp.moveaxis(v.reshape(b, nk, ck, h, d), 1, 0)
+    vc = jnp.moveaxis(v.reshape(b, nk, ck, h, dv), 1, 0)
 
     def q_body(_, q_in):
         qi, qblk = q_in                            # qblk (B, Cq, H, D)
@@ -150,7 +150,7 @@ def flash_attention(q, k, v, *, causal: bool, q_chunk: int, kv_chunk: int,
 
         m0 = jnp.full((b, h, cq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, h, cq), jnp.float32)
-        a0 = jnp.zeros((b, h, cq, d), jnp.float32)
+        a0 = jnp.zeros((b, h, cq, dv), jnp.float32)
 
         if unroll:
             # Trace-time causal skipping: only live kv chunks are emitted,
@@ -182,7 +182,7 @@ def flash_attention(q, k, v, *, causal: bool, q_chunk: int, kv_chunk: int,
         _, out = jax.lax.scan(q_body, None, (jnp.arange(nq), qc))
     # (Nq, B, H, Cq, D) -> (B, S, H, D)
     out = jnp.moveaxis(out, 0, 1).transpose(0, 1, 3, 2, 4)
-    return out.reshape(b, s, h, d)[:, :s_orig]
+    return out.reshape(b, s, h, dv)[:, :s_orig]
 
 
 def attention(p, x, cfg, *, positions, causal=True, rope=True,

@@ -29,6 +29,17 @@ class ArchConfig:
     first_dense: int = 0             # leading dense-FFN layers (deepseek-moe)
     capacity_factor: float = 1.25
     moe_impl: str = "dense"          # dense (global sort) | ep (shard_map)
+    router: str = "softmax"          # softmax | sigmoid (top-k of the
+    #                                  score plus a selection-only bias)
+    routed_scale: float = 1.0        # gates x this after normalizing
+    experts_held: int = 0            # routed experts of each layer held
+    #                                  here (0: all n_experts)
+    expert_offset: int = 0           # id of the first held expert
+    # --- MLA (deepseek-v2/v3 latent attention; 0 = GQA) ---
+    kv_lora_rank: int = 0            # cached latent width
+    qk_nope_dim: int = 0             # per-head q/k dims without rope
+    qk_rope_dim: int = 0             # rope dims: per-head q, one shared k
+    v_head_dim: int = 0
     # --- SSM (mamba2 / SSD) ---
     d_state: int = 0
     ssm_headdim: int = 64
@@ -50,6 +61,7 @@ class ArchConfig:
     rope_theta: float = 10000.0
     tie_embeddings: bool = False
     norm: str = "rmsnorm"            # rmsnorm | layernorm
+    norm_eps: float = 1e-6           # rmsnorm epsilon
     act: str = "silu"                # silu (swiglu) | gelu (plain mlp)
     # --- paper technique ---
     quant: str = "none"              # none | ternary | ternary_packed
@@ -63,6 +75,14 @@ class ArchConfig:
     attn_bf16_scores: bool = False   # bf16 score tiles (f32 m/l accum)
     norm_bf16_mul: bool = False      # rmsnorm: f32 reduce, bf16 normalize
     loss_chunk: int = 512            # vocab-loss sequence chunking
+
+    @property
+    def n_held(self) -> int:         # routed experts of a layer held here
+        return self.experts_held or self.n_experts
+
+    @property
+    def latent_dim(self) -> int:     # MLA cache row: latent + shared k_pe
+        return self.kv_lora_rank + self.qk_rope_dim
 
     @property
     def d_inner(self) -> int:        # SSD inner width
@@ -93,7 +113,7 @@ SHAPES = {
 
 # Sub-quadratic sequence mixing is required for long_500k (per assignment):
 # only the SSM / hybrid families run it; pure full-attention archs record a
-# skip (DESIGN.md §5).
+# skip.
 LONG_CONTEXT_FAMILIES = ("ssm", "hybrid")
 
 
@@ -122,6 +142,12 @@ def reduce_for_smoke(cfg: ArchConfig) -> ArchConfig:
         kw.update(n_experts=8, topk=min(cfg.topk, 2), d_ff_expert=32,
                   n_shared_experts=min(cfg.n_shared_experts, 1),
                   first_dense=min(cfg.first_dense, 1))
+        if cfg.experts_held:         # the same share of the 8 experts
+            kw.update(experts_held=max(
+                1, 8 * cfg.experts_held // cfg.n_experts), expert_offset=0)
+    if cfg.kv_lora_rank:
+        kw.update(kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+                  v_head_dim=16)
     if cfg.d_state:
         kw.update(d_state=16, ssm_headdim=16, chunk=16)
     if cfg.attn_every:

@@ -1,21 +1,26 @@
-"""Serving paths: prefill-with-cache and single-token decode steps.
+"""Serving paths: prefix-aware prefill and single-token decode steps.
 
 Cache layout: stacked over layers, (L, B, T, Hk, Dh), with the sequence
-axis sharded over `model` (flash-decoding; see models.attention).  SSM
+axis sharded over `model` (flash-decoding; see models.attention).  MLA
+archs (``cfg.kv_lora_rank``) cache one latent row per position instead,
+``{"lat": (L, B, T, kv_lora_rank + qk_rope_dim)}`` (models.mla).  SSM
 archs carry (L, B, H, P, N) states + conv buffers instead — constant in
 sequence length, which is what makes the long_500k cell tractable.
+
+MoE layers run the held-share, no-drop dispatch (``moe.apply_held``);
+prefill, and the decode step with ``with_routes``, also return the
+expert ids that each token chose in each routed layer,
+``(L_moe, B, S, k)``.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 
 from repro.models import attention as attn
 from repro.models import common as C
-from repro.models import mamba2, mlp, moe
+from repro.models import mamba2, mla, mlp, moe
 from repro.models import transformer as TF
 from repro.models.common import BATCH, MODEL, maybe_scan, shard
 from repro.models.config import ArchConfig
@@ -33,6 +38,9 @@ def init_caches(cfg: ArchConfig, batch: int, max_len: int):
         "k": jnp.zeros((n, batch, max_len, hk, dh), kdt),
         "v": jnp.zeros((n, batch, max_len, hk, dh), kdt),
     }
+    if cfg.kv_lora_rank:
+        return {"kv": {"lat": jnp.zeros(
+            (cfg.n_layers, batch, max_len, cfg.latent_dim), kdt)}}
     if cfg.family in ("dense", "vlm", "moe"):
         return {"kv": kv(cfg.n_layers)}
     stack = lambda st: jax.tree.map(  # noqa: E731
@@ -57,6 +65,8 @@ def cache_pspecs(cfg: ArchConfig):
     from jax.sharding import PartitionSpec as P
     kvspec = {"k": P(None, BATCH, MODEL, None, None),
               "v": P(None, BATCH, MODEL, None, None)}
+    if cfg.kv_lora_rank:
+        return {"kv": {"lat": P(None, BATCH, MODEL, None)}}
     if cfg.family in ("dense", "vlm", "moe"):
         return {"kv": kvspec}
     ssm_spec = {"conv_x": P(None, BATCH, None, MODEL),
@@ -78,12 +88,16 @@ def cache_pspecs(cfg: ArchConfig):
 
 
 def decode_step(p, token, caches, pos, cfg: ArchConfig, *, unroll=False,
-                frames_cache=None):
-    """token (B, 1) int32; pos scalar int32.  Returns (logits, new caches)."""
+                frames_cache=None, with_routes=False):
+    """token (B, 1) int32; pos scalar int32.  Returns (logits, new caches),
+    and with ``with_routes`` the routed expert ids (L_moe, B, 1, k) last
+    (attention families only)."""
     x = TF._embed(p, token, cfg)
+    routes = None
 
     if cfg.family in ("dense", "vlm", "moe"):
-        x, kv = _decode_attn_stack(p, x, caches["kv"], pos, cfg, unroll)
+        x, kv, routes = _decode_attn_stack(p, x, caches["kv"], pos, cfg,
+                                           unroll)
         new_caches = {"kv": kv}
     elif cfg.family == "ssm":
         x, st = _decode_ssm_stack(p, x, caches["ssm"], cfg, unroll)
@@ -101,52 +115,68 @@ def decode_step(p, token, caches, pos, cfg: ArchConfig, *, unroll=False,
 
     x = TF._norm(cfg, p["ln_f"], x)
     logits = x @ TF.head_weight(p, cfg)
-    return shard(logits, BATCH, None, MODEL), new_caches
+    out = shard(logits, BATCH, None, MODEL), new_caches
+    return out + (routes,) if with_routes else out
+
+
+def _ffn(lp, xn, cfg):
+    """A block's FFN: ``(y, routed expert ids)``, the ids None for a
+    dense layer."""
+    if "moe" in lp:
+        return moe.apply_held(lp["moe"], xn, cfg)
+    return mlp.apply(lp["mlp"], xn, cfg), None
+
+
+def _run_stacks(body, x, p, cfg, caches, unroll):
+    """Scan ``body(carry, (layer params, layer caches))`` over every layer
+    stack; returns ``(x, per-layer outputs concatenated over the stacks,
+    routes of the last stack)``.  A MoE model's leading dense layers use
+    the first cache slots; each stack indexes the whole cache per layer
+    rather than scanning a sliced copy of it."""
+    nd = cfg.first_dense if cfg.family == "moe" else 0
+    if not nd:
+        x, (out, routes) = maybe_scan(body, x, (p["layers"], caches),
+                                      unroll=unroll)
+        return x, out, routes
+
+    def indexed(carry, inp):
+        lp, layer = inp
+        return body(carry, (lp, jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+            caches)))
+
+    outs = []
+    for layers, first in ((p["dense_layers"], 0), (p["layers"], nd)):
+        n = jax.tree.leaves(layers)[0].shape[0]
+        x, (out, routes) = maybe_scan(
+            indexed, x, (layers, first + jnp.arange(n)), unroll=unroll)
+        outs.append(out)
+    return x, jax.tree.map(lambda *a: jnp.concatenate(a), *outs), routes
 
 
 def _decode_attn_stack(p, x, kv, pos, cfg, unroll):
+    """-> (x, new kv, routes).  GQA layers return their updated caches;
+    MLA layers return only their new row, set into the cache after."""
     def body(carry, inp):
-        h = carry
-        lp, ck, cv = inp
-        a, newc = attn.decode_attention(
-            lp["attn"], TF._norm(cfg, lp["ln1"], h), cfg,
-            {"k": ck, "v": cv}, pos)
-        h = h + a
-        if cfg.family == "moe" and "moe" in lp:
-            y, _ = moe.apply(lp["moe"], TF._norm(cfg, lp["ln2"], h), cfg)
+        lp, c = inp
+        xn = TF._norm(cfg, lp["ln1"], carry)
+        if cfg.kv_lora_rank:
+            a, row = mla.decode_attention(lp["attn"], xn, cfg, c["lat"],
+                                          pos)
+            new = {"lat": row}
         else:
-            y = mlp.apply(lp["mlp"], TF._norm(cfg, lp["ln2"], h), cfg)
-        return h + y, (newc["k"], newc["v"])
+            a, new = attn.decode_attention(lp["attn"], xn, cfg, c, pos)
+        h = carry + a
+        y, routes = _ffn(lp, TF._norm(cfg, lp["ln2"], h), cfg)
+        return h + y, (new, routes)
 
-    if cfg.family == "moe" and cfg.first_dense:
-        # leading dense layers use the first cache slots
-        nd = cfg.first_dense
-        dense_cfg = cfg.replace(d_ff=cfg.d_ff or 4 * cfg.d_model)
-        xs_d = (p["dense_layers"], kv["k"][:nd], kv["v"][:nd])
-        x, kv_d = maybe_scan(
-            functools.partial(_dense_decode_body, cfg=dense_cfg, pos=pos),
-            x, xs_d, unroll=unroll)
-        x, kv_m = maybe_scan(body, x,
-                             (p["layers"], kv["k"][nd:], kv["v"][nd:]),
-                             unroll=unroll)
-        k = jnp.concatenate([kv_d[0], kv_m[0]])
-        v = jnp.concatenate([kv_d[1], kv_m[1]])
-        return x, {"k": k, "v": v}
-
-    x, (k, v) = maybe_scan(body, x, (p["layers"], kv["k"], kv["v"]),
-                           unroll=unroll)
-    return x, {"k": k, "v": v}
-
-
-def _dense_decode_body(carry, inp, *, cfg, pos):
-    h = carry
-    lp, ck, cv = inp
-    a, newc = attn.decode_attention(
-        lp["attn"], TF._norm(cfg, lp["ln1"], h), cfg, {"k": ck, "v": cv},
-        pos)
-    h = h + a
-    y = mlp.apply(lp["mlp"], TF._norm(cfg, lp["ln2"], h), cfg)
-    return h + y, (newc["k"], newc["v"])
+    x, new, routes = _run_stacks(body, x, p, cfg, kv, unroll)
+    if cfg.kv_lora_rank:
+        rows = jnp.arange(x.shape[0])
+        pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), rows.shape)
+        new = {"lat": kv["lat"].at[:, rows, pos].set(
+            new["lat"].astype(kv["lat"].dtype))}
+    return x, new, routes
 
 
 _SSM_KEYS = ("conv_x", "conv_b", "conv_c", "ssm")
@@ -249,99 +279,31 @@ def _decode_encdec_stack(p, x, kv, cross, pos, cfg, unroll):
 
 
 # ---------------------------------------------------------------------------
-# Prefill with cache collection (serving runtime)
-# ---------------------------------------------------------------------------
-
-
-def prefill_with_cache(p, batch, cfg: ArchConfig, max_len: int, *,
-                       unroll=False):
-    """Run the full prompt, return (last logits, populated caches).
-
-    Implemented for the attention families (the serving runtime's prefill);
-    SSM/hybrid prefill uses the chunked SSD path with state return.
-    """
-    tokens = batch["tokens"]
-    b, s = tokens.shape
-    positions = jnp.arange(s)[None]
-    x = TF._embed(p, tokens, cfg)
-
-    if cfg.family in ("dense", "vlm", "moe"):
-        def body(carry, lp):
-            h = carry
-            causal_cfg = cfg
-            a, (k, v) = attn.attention(
-                lp["attn"], TF._norm(cfg, lp["ln1"], h), causal_cfg,
-                positions=positions, unroll=unroll)
-            h = h + a
-            if cfg.family == "moe" and "moe" in lp:
-                y, _ = moe.apply(lp["moe"], TF._norm(cfg, lp["ln2"], h), cfg)
-            else:
-                y = mlp.apply(lp["mlp"], TF._norm(cfg, lp["ln2"], h), cfg)
-            pad = max_len - s
-            kc = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            vc = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            return h + y, (kc.astype(jnp.bfloat16), vc.astype(jnp.bfloat16))
-
-        layers = p["layers"]
-        if cfg.family == "moe" and cfg.first_dense:
-            dense_cfg = cfg.replace(d_ff=cfg.d_ff or 4 * cfg.d_model)
-            x, kvd = maybe_scan(
-                functools.partial(
-                    _prefill_dense_body, cfg=dense_cfg,
-                    positions=positions, max_len=max_len, unroll=unroll),
-                x, p["dense_layers"], unroll=unroll)
-            x, kvm = maybe_scan(body, x, layers, unroll=unroll)
-            k = jnp.concatenate([kvd[0], kvm[0]])
-            v = jnp.concatenate([kvd[1], kvm[1]])
-        else:
-            x, (k, v) = maybe_scan(body, x, layers, unroll=unroll)
-        caches = {"kv": {"k": k, "v": v}}
-    elif cfg.family in ("ssm", "hybrid"):
-        raise NotImplementedError(
-            "SSM prefill uses transformer.forward_logits + state return; "
-            "see serving runtime")
-    else:
-        raise ValueError(cfg.family)
-
-    x = TF._norm(cfg, p["ln_f"], x[:, -1:])
-    logits = x @ TF.head_weight(p, cfg)
-    return shard(logits, BATCH, None, MODEL), caches
-
-
-def _prefill_dense_body(carry, lp, *, cfg, positions, max_len, unroll):
-    h = carry
-    s = h.shape[1]
-    a, (k, v) = attn.attention(
-        lp["attn"], TF._norm(cfg, lp["ln1"], h), cfg,
-        positions=positions, unroll=unroll)
-    h = h + a
-    y = mlp.apply(lp["mlp"], TF._norm(cfg, lp["ln2"], h), cfg)
-    pad = max_len - s
-    kc = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    vc = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    return h + y, (kc.astype(jnp.bfloat16), vc.astype(jnp.bfloat16))
-
-
-# ---------------------------------------------------------------------------
 # Prefix-aware prefill (paged serving runtime)
 # ---------------------------------------------------------------------------
 
 
-def _suffix_attn_block(lp, h, prefix_k, prefix_v, positions, n_cached,
-                       cfg, unroll, ffn):
-    """One transformer block over *suffix* positions against cached
-    prefix KV.
+def empty_prefix(cfg: ArchConfig, batch: int = 1) -> dict:
+    """A cached prefix of length 0, in the layout of ``prefix_kv``."""
+    if cfg.kv_lora_rank:
+        return {"lat": jnp.zeros((cfg.n_layers, batch, 0, cfg.latent_dim),
+                                 jnp.bfloat16)}
+    return {n: jnp.zeros((cfg.n_layers, batch, 0, cfg.n_kv, cfg.d_head),
+                         jnp.bfloat16) for n in ("k", "v")}
 
-    ``prefix_k/v (B, C, Hk, Dh)`` hold the post-rope rows for absolute
-    positions ``0..C-1`` (exactly what the cache stores), so attention
-    over ``concat(prefix, suffix)`` with ``q_offset=C`` reproduces the
-    full-prompt computation for every suffix row — the suffix queries
-    see identical keys at identical positions.
+
+def _suffix_attn(lp, xa, prefix, positions, n_cached, cfg, unroll):
+    """GQA over *suffix* positions against the cached prefix KV.
+
+    ``prefix["k"/"v"] (B, C, Hk, Dh)`` hold the post-rope rows for
+    absolute positions ``0..C-1`` (exactly what the cache stores), so
+    attention over ``concat(prefix, suffix)`` with ``q_offset=C``
+    reproduces the full-prompt computation for every suffix row — the
+    suffix queries see identical keys at identical positions.
     """
-    xa = TF._norm(cfg, lp["ln1"], h)
     q, k, v = attn._project_qkv(lp["attn"], xa, cfg, positions, True)
-    kf = jnp.concatenate([prefix_k.astype(q.dtype), k], axis=1)
-    vf = jnp.concatenate([prefix_v.astype(q.dtype), v], axis=1)
+    kf = jnp.concatenate([prefix["k"].astype(q.dtype), k], axis=1)
+    vf = jnp.concatenate([prefix["v"].astype(q.dtype), v], axis=1)
     g = cfg.n_heads // cfg.n_kv
     kr = jnp.repeat(kf, g, axis=2) if g > 1 else kf
     vr = jnp.repeat(vf, g, axis=2) if g > 1 else vf
@@ -357,9 +319,8 @@ def _suffix_attn_block(lp, h, prefix_k, prefix_v, positions, n_cached,
         out = shard(out, BATCH, MODEL, None, None)
     b, s = out.shape[:2]
     a = C.linear(lp["attn"]["wo"], out.reshape(b, s, -1), quant=cfg.quant)
-    h = h + shard(a, BATCH, None, None)
-    y = ffn(lp, TF._norm(cfg, lp["ln2"], h))
-    return h + y, (k.astype(jnp.bfloat16), v.astype(jnp.bfloat16))
+    return (shard(a, BATCH, None, None),
+            {"k": k.astype(jnp.bfloat16), "v": v.astype(jnp.bfloat16)})
 
 
 def prefill_with_prefix(p, tokens, prefix_kv, cfg: ArchConfig, *,
@@ -368,55 +329,38 @@ def prefill_with_prefix(p, tokens, prefix_kv, cfg: ArchConfig, *,
     KV rows were served by the prefix cache.
 
     tokens (B, S) are the suffix tokens at absolute positions
-    ``C .. C+S-1``; ``prefix_kv = {"k"/"v": (L, B, C, Hk, Dh)}`` is the
-    gathered cached prefix (C may be 0).  Returns
-    ``(logits (B, S, V), suffix kv (L, B, S, Hk, Dh))`` — all suffix
-    logits, so bucket-padded callers can pick row ``n_real - 1``.
+    ``C .. C+S-1``; ``prefix_kv`` is the gathered cached prefix (C may be
+    0): ``{"k"/"v": (L, B, C, Hk, Dh)}``, or ``{"lat": (L, B, C, R)}``
+    for MLA.  Returns ``(logits (B, S, V), suffix kv, routes)``: the kv
+    in the same layout with S rows, all suffix logits (so bucket-padded
+    callers can pick row ``n_real - 1``), and the routed expert ids
+    ``(L_moe, B, S, k)`` (None for a model with no routed layer).
     """
     if cfg.family not in ("dense", "vlm", "moe"):
         raise NotImplementedError(
             f"prefix prefill is attention-family only, got {cfg.family}")
-    b, s = tokens.shape
-    n_cached = prefix_kv["k"].shape[2]
-    positions = n_cached + jnp.arange(s)[None]
+    n_cached = jax.tree.leaves(prefix_kv)[0].shape[2]
+    positions = n_cached + jnp.arange(tokens.shape[1])[None]
     x = TF._embed(p, tokens, cfg)
 
-    def moe_ffn(lp, xn):
-        if cfg.family == "moe" and "moe" in lp:
-            y, _ = moe.apply(lp["moe"], xn, cfg)
-            return y
-        return mlp.apply(lp["mlp"], xn, cfg)
-
     def body(carry, inp):
-        lp, pk, pv = inp
-        return _suffix_attn_block(lp, carry, pk, pv, positions, n_cached,
-                                  cfg, unroll, moe_ffn)
+        lp, prefix = inp
+        xa = TF._norm(cfg, lp["ln1"], carry)
+        if cfg.kv_lora_rank:
+            a, rows = mla.attention(lp["attn"], xa, cfg, positions=positions,
+                                    prefix=prefix["lat"], unroll=unroll)
+            new = {"lat": rows.astype(jnp.bfloat16)}
+        else:
+            a, new = _suffix_attn(lp, xa, prefix, positions, n_cached, cfg,
+                                  unroll)
+        h = carry + a
+        y, routes = _ffn(lp, TF._norm(cfg, lp["ln2"], h), cfg)
+        return h + y, (new, routes)
 
-    pk, pv = prefix_kv["k"], prefix_kv["v"]
-    if cfg.family == "moe" and cfg.first_dense:
-        nd = cfg.first_dense
-        dense_cfg = cfg.replace(d_ff=cfg.d_ff or 4 * cfg.d_model)
-
-        def dense_body(carry, inp):
-            lp, dk, dv = inp
-            ffn = lambda lp_, xn: mlp.apply(lp_["mlp"], xn, dense_cfg)  # noqa: E731
-            return _suffix_attn_block(lp, carry, dk, dv, positions,
-                                      n_cached, dense_cfg, unroll, ffn)
-
-        x, (kd, vd) = maybe_scan(
-            dense_body, x, (p["dense_layers"], pk[:nd], pv[:nd]),
-            unroll=unroll)
-        x, (km, vm) = maybe_scan(
-            body, x, (p["layers"], pk[nd:], pv[nd:]), unroll=unroll)
-        k = jnp.concatenate([kd, km])
-        v = jnp.concatenate([vd, vm])
-    else:
-        x, (k, v) = maybe_scan(body, x, (p["layers"], pk, pv),
-                               unroll=unroll)
-
+    x, kv, routes = _run_stacks(body, x, p, cfg, prefix_kv, unroll)
     x = TF._norm(cfg, p["ln_f"], x)
     logits = x @ TF.head_weight(p, cfg)
-    return shard(logits, BATCH, None, MODEL), {"k": k, "v": v}
+    return shard(logits, BATCH, None, MODEL), kv, routes
 
 
 def ssm_prefill(p, tokens, caches, cfg: ArchConfig, start_pos=0):

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 
 from repro.models import attention as attn
 from repro.models import common as C
-from repro.models import losses, mamba2, mlp, moe
+from repro.models import losses, mamba2, mla, mlp, moe
 from repro.models.common import BATCH, MODEL, maybe_scan, shard
 from repro.models.config import ArchConfig
 
@@ -50,36 +50,46 @@ def _norm_init(cfg, d=None):
 
 def _norm(cfg, p, x):
     if cfg.norm == "rmsnorm":
-        return C.rmsnorm(p, x, bf16_mul=cfg.norm_bf16_mul)
+        return C.rmsnorm(p, x, eps=cfg.norm_eps, bf16_mul=cfg.norm_bf16_mul)
     return C.layernorm(p, x)
+
+
+def _attn_init(key, cfg):
+    """A block's self-attention: latent (MLA) or GQA."""
+    return mla.init(key, cfg) if cfg.kv_lora_rank else attn.init(key, cfg)
 
 
 def dense_block_init(key, cfg):
     ks = jax.random.split(key, 2)
-    return {"ln1": _norm_init(cfg), "attn": attn.init(ks[0], cfg),
+    return {"ln1": _norm_init(cfg), "attn": _attn_init(ks[0], cfg),
             "ln2": _norm_init(cfg), "mlp": mlp.init(ks[1], cfg)}
+
+
+def _self_attention(p, x, cfg, positions, unroll, causal=True, rope=True):
+    if cfg.kv_lora_rank:
+        return mla.attention(p, x, cfg, positions=positions,
+                             unroll=unroll)[0]
+    return attn.attention(p, x, cfg, positions=positions, causal=causal,
+                          rope=rope, unroll=unroll)[0]
 
 
 def dense_block(p, x, cfg, positions, *, unroll=False, causal=True,
                 rope=True):
-    h, _ = attn.attention(p["attn"], _norm(cfg, p["ln1"], x), cfg,
-                          positions=positions, causal=causal, rope=rope,
-                          unroll=unroll)
-    x = x + h
+    x = x + _self_attention(p["attn"], _norm(cfg, p["ln1"], x), cfg,
+                            positions, unroll, causal, rope)
     x = x + mlp.apply(p["mlp"], _norm(cfg, p["ln2"], x), cfg)
     return x
 
 
 def moe_block_init(key, cfg):
     ks = jax.random.split(key, 2)
-    return {"ln1": _norm_init(cfg), "attn": attn.init(ks[0], cfg),
+    return {"ln1": _norm_init(cfg), "attn": _attn_init(ks[0], cfg),
             "ln2": _norm_init(cfg), "moe": moe.init(ks[1], cfg)}
 
 
 def moe_block(p, x, cfg, positions, *, unroll=False):
-    h, _ = attn.attention(p["attn"], _norm(cfg, p["ln1"], x), cfg,
-                          positions=positions, unroll=unroll)
-    x = x + h
+    x = x + _self_attention(p["attn"], _norm(cfg, p["ln1"], x), cfg,
+                            positions, unroll)
     y, aux = moe.apply(p["moe"], _norm(cfg, p["ln2"], x), cfg)
     return x + y, aux
 

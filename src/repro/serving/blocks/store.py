@@ -4,9 +4,11 @@ Two stores share the :class:`~repro.serving.blocks.pool.BlockPool`'s id
 space:
 
 * :class:`KVPagedStore` — attention families.  KV rows live in
-  ``(L, num_blocks, block_size, Hk, Dh)`` pages; a per-sequence block
-  table maps logical positions to physical blocks, and the decode step
-  *gathers* through the table instead of indexing a contiguous cache.
+  ``(L, num_blocks, block_size, Hk, Dh)`` pages, K and V (or, for MLA,
+  one ``(L, num_blocks, block_size, latent_dim)`` page of latent rows);
+  a per-sequence block table maps logical positions to physical blocks,
+  and the decode step *gathers* through the table instead of indexing a
+  contiguous cache.
   With ``codec="trit"`` the pages hold **ternarized** rows packed 5
   trits/byte (`repro.core.codec` layout) plus one scale per (position,
   head) — 1.6 bits per element, so a fixed HBM budget holds ~5x the
@@ -76,19 +78,32 @@ def ternarize_rows(v: Array) -> tuple[Array, Array]:
 
 
 class KVPagedStore:
-    """Paged KV pages + pure gather/scatter over block tables."""
+    """Paged KV pages + pure gather/scatter over block tables.
+
+    ``latent_dim > 0`` stores MLA pages instead of K and V: one row of
+    ``latent_dim`` values per position and layer, under the name
+    ``"lat"`` (raw codec only)."""
 
     def __init__(self, n_layers: int, num_blocks: int, block_size: int,
                  n_kv: int, d_head: int, dtype="bfloat16",
-                 codec_name: str = "raw"):
+                 codec_name: str = "raw", latent_dim: int = 0):
         if codec_name not in ("raw", "trit"):
             raise ValueError(f"codec must be 'raw' or 'trit', "
                              f"got {codec_name!r}")
+        if latent_dim and codec_name != "raw":
+            raise ValueError(
+                f"kv_codec={codec_name!r} is not supported for MLA latent "
+                f"pages; use 'raw'")
         self.n_layers, self.num_blocks = n_layers, num_blocks
         self.block_size, self.n_kv, self.d_head = block_size, n_kv, d_head
+        self.latent_dim = latent_dim
         self.dtype = jnp.dtype(dtype)
         self.codec = codec_name
-        if codec_name == "raw":
+        self.names = ("lat",) if latent_dim else ("k", "v")
+        if latent_dim:
+            lat = (n_layers, num_blocks, block_size, latent_dim)
+            self.pages = {"lat": jnp.zeros(lat, self.dtype)}
+        elif codec_name == "raw":
             kv = (n_layers, num_blocks, block_size, n_kv, d_head)
             self.pages = {"k": jnp.zeros(kv, self.dtype),
                           "v": jnp.zeros(kv, self.dtype)}
@@ -104,7 +119,11 @@ class KVPagedStore:
     # -- sizing -------------------------------------------------------------
 
     def bytes_per_block(self) -> int:
-        """Physical bytes of one block across all layers (both of K/V)."""
+        """Physical bytes of one block across all layers (both of K/V,
+        or the latent rows)."""
+        if self.latent_dim:
+            return (self.n_layers * self.block_size * self.latent_dim
+                    * self.dtype.itemsize)
         per = self.block_size * self.n_kv
         if self.codec == "raw":
             elem = per * self.d_head * self.dtype.itemsize
@@ -132,9 +151,10 @@ class KVPagedStore:
 
     def gather(self, pages: dict, tables: Array) -> dict:
         """``tables (B, MB) int32`` -> contiguous KV view
-        ``{"k"/"v": (L, B, MB*block_size, Hk, Dh)}``."""
+        ``{"k"/"v": (L, B, MB*block_size, Hk, Dh)}`` (MLA: ``{"lat":
+        (L, B, MB*block_size, latent_dim)}``)."""
         out = {}
-        for name in ("k", "v"):
+        for name in self.names:
             g = pages[name][:, tables]       # (L, B, MB, BS, Hk, [Dh|PW])
             sc = (pages[f"{name}_scale"][:, tables]
                   if self.codec == "trit" else None)
@@ -146,12 +166,13 @@ class KVPagedStore:
     def write_rows(self, pages: dict, tables: Array, pos: Array,
                    rows: dict) -> dict:
         """Scatter one decode step's new rows ``{"k"/"v": (L, B, Hk, Dh)}``
-        at per-sequence positions ``pos (B,)`` through the tables."""
+        (or ``{"lat": (L, B, latent_dim)}``) at per-sequence positions
+        ``pos (B,)`` through the tables."""
         b = pos.shape[0]
         blocks = tables[jnp.arange(b), pos // self.block_size]
         off = pos % self.block_size
         new = dict(pages)
-        for name in ("k", "v"):
+        for name in self.names:
             enc = self._encode(rows[name])
             new[name] = pages[name].at[:, blocks, off].set(enc[""])
             if self.codec == "trit":
@@ -168,7 +189,7 @@ class KVPagedStore:
         padding) are routed to the null block, which never holds live
         data.
         """
-        s = kv["k"].shape[1]
+        s = kv[self.names[0]].shape[1]
         j = jnp.arange(s)
         posn = start + j
         valid = j < n_real
@@ -176,7 +197,7 @@ class KVPagedStore:
         blocks = jnp.where(valid, table[idx], NULL_BLOCK)
         off = jnp.where(valid, posn % self.block_size, 0)
         new = dict(pages)
-        for name in ("k", "v"):
+        for name in self.names:
             enc = self._encode(kv[name])
             new[name] = pages[name].at[:, blocks, off].set(enc[""])
             if self.codec == "trit":

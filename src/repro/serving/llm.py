@@ -21,6 +21,13 @@ is that loop rebuilt on the paged-state subsystem
   of a KV prefix), optionally packed 5 trits/byte via
   `repro.core.codec` for ternary states.
 
+MoE models count, on the device inside the paged decode and prefill
+programs, the routed (token, expert) assignments of live tokens by
+whether this executor holds the expert
+(:attr:`LLMExecutor.moe_assignments`); the totals reach the metrics
+registry as ``moe_assignments_total{model, site, share}`` only when the
+registry is read, so counting adds no blocking read to a step.
+
 ``ServerConfig(paged=False)`` keeps a contiguous cache but runs the
 *same* prefill/decode math, so paged-vs-contiguous bit-exactness is
 testable by construction (see tests/test_paged_state.py).  Exact
@@ -39,9 +46,9 @@ import numpy as np
 
 from repro.models import decoding as DEC
 from repro.models.config import ArchConfig
-from repro.serving.blocks import (BlockPool, KVPagedStore, OutOfBlocks,
-                                  PagedSequenceManager, PrefixCache,
-                                  StatePagedStore, chain_hashes)
+from repro.serving.blocks import (NULL_BLOCK, BlockPool, KVPagedStore,
+                                  OutOfBlocks, PagedSequenceManager,
+                                  PrefixCache, StatePagedStore, chain_hashes)
 from repro.serving.executors import ExecutionReport, Executor
 
 _ATTN_FAMILIES = ("dense", "vlm", "moe")
@@ -83,6 +90,20 @@ class PrefillResult:
     tokens_computed: int     # suffix tokens actually run (excl. padding)
 
 
+_SITES, _SHARES = ("decode", "prefill"), ("held", "elsewhere")
+
+
+def _route_counts(routes, live, cfg: ArchConfig):
+    """``[held, elsewhere]`` int32: the routed assignments of the live
+    tokens, ``routes (L, B, S, k)`` expert ids and ``live (B, S)``, by
+    whether the expert is one of the ``cfg.n_held`` held here."""
+    lo = cfg.expert_offset
+    held = (routes >= lo) & (routes < lo + cfg.n_held)
+    live = jnp.broadcast_to(live[None, :, :, None], routes.shape)
+    n_held = jnp.sum(held & live, dtype=jnp.int32)
+    return jnp.stack([n_held, jnp.sum(live, dtype=jnp.int32) - n_held])
+
+
 def _bucket(n: int, floor: int) -> int:
     """Smallest power-of-2 >= n, floored — bounds prefill jit variants."""
     b = floor
@@ -114,6 +135,12 @@ class LLMExecutor(Executor):
         self._prefill_fns: dict = {}                   # jit variant cache
         self.prefill_tokens = 0          # prompt tokens admitted
         self.prefill_tokens_computed = 0  # of those, actually run
+        # routed assignments [site][share] (_SITES x _SHARES), summed on
+        # the device by the paged programs; None for an unrouted model
+        self.moe_assignments = (
+            jnp.zeros((2, 2), jnp.int32)
+            if cfg.family == "moe" and scfg.paged else None)
+        self._published = np.zeros((2, 2), np.int64)
 
         bs = scfg.block_size
         self.blocks_per_seq = scfg.max_len // bs
@@ -158,18 +185,30 @@ class LLMExecutor(Executor):
                                             scfg.block_size)
         self.kv_store = KVPagedStore(
             cfg.n_layers, num_blocks, scfg.block_size, cfg.n_kv,
-            cfg.d_head, dtype=cfg.kv_dtype, codec_name=scfg.kv_codec)
+            cfg.d_head, dtype=cfg.kv_dtype, codec_name=scfg.kv_codec,
+            latent_dim=cfg.latent_dim)
         store = self.kv_store
 
-        def step(p, tok, pages, tables, pos):
+        def step(p, tok, pages, tables, pos, *acc):
             kv = store.gather(pages, tables)
-            logits, new = DEC.decode_step(p, tok, {"kv": kv}, pos, cfg)
+            out = DEC.decode_step(p, tok, {"kv": kv}, pos, cfg,
+                                  with_routes=bool(acc))
+            logits, new = out[:2]
             b = pos.shape[0]
-            rows = {n: new["kv"][n][:, jnp.arange(b), pos]
-                    for n in ("k", "v")}
-            return logits, store.write_rows(pages, tables, pos, rows)
+            rows = {n: a[:, jnp.arange(b), pos]
+                    for n, a in new["kv"].items()}
+            pages = store.write_rows(pages, tables, pos, rows)
+            if not acc:
+                return logits, pages
+            # an empty slot's table is all NULL_BLOCK
+            live = (tables[:, 0] != NULL_BLOCK)[:, None]
+            return logits, pages, acc[0].at[0].add(
+                _route_counts(out[2], live, cfg))
 
         self._decode_fn = jax.jit(step)
+        self._count_prefill = jax.jit(lambda acc, routes, n: acc.at[1].add(
+            _route_counts(routes, (jnp.arange(routes.shape[2]) < n)[None],
+                         cfg)))
 
     def _on_evict(self, bid: int, h: str) -> None:
         """LRU eviction callback: drop the cache mapping, leave a trace
@@ -179,6 +218,28 @@ class LLMExecutor(Executor):
         self.obs.metrics.counter(
             "prefix_evictions_total",
             "cached blocks evicted under pool pressure").inc()
+
+    # -- observability -------------------------------------------------------
+
+    def bind_obs(self, obs, model: str = "") -> None:
+        super().bind_obs(obs, model)
+        if self.moe_assignments is not None:
+            obs.metrics.collect(f"moe_assignments:{model}",
+                                self._publish_assignments)
+
+    def _publish_assignments(self) -> None:
+        """Bring the device-side totals into ``moe_assignments_total``:
+        one blocking read, made only when the registry is read."""
+        now = np.asarray(self.moe_assignments, np.int64)
+        c = self.obs.metrics.counter(
+            "moe_assignments_total", "routed (token, expert) assignments "
+            "of live tokens, by site and by whether this executor holds "
+            "the expert")
+        for i, site in enumerate(_SITES):
+            for j, share in enumerate(_SHARES):
+                c.inc(float(now[i, j] - self._published[i, j]),
+                      model=self.model, site=site, share=share)
+        self._published = now
 
     # -- engine protocol ----------------------------------------------------
 
@@ -349,7 +410,10 @@ class LLMExecutor(Executor):
         padded = np.zeros((1, sb), np.int32)
         padded[0, :n_real] = suffix
         fn = self._suffix_fn(n_cached, sb)
-        logits, kv = fn(self.params, jnp.asarray(padded), prefix_kv)
+        logits, kv, routes = fn(self.params, jnp.asarray(padded), prefix_kv)
+        if self.moe_assignments is not None:
+            self.moe_assignments = self._count_prefill(
+                self.moe_assignments, routes, jnp.int32(n_real))
         return logits[0, n_real - 1], kv, n_real
 
     def _prefill_paged(self, uid, slot, tokens) -> PrefillResult:
@@ -363,13 +427,11 @@ class LLMExecutor(Executor):
             self.manager.table_array(uid, self.blocks_per_seq))
         prefix_kv = self.kv_store.gather(
             self.kv_store.pages, table_row[None, :c // bs]) if c else \
-            {n: jnp.zeros((self.cfg.n_layers, 1, 0, self.cfg.n_kv,
-                           self.cfg.d_head), jnp.bfloat16)
-             for n in ("k", "v")}
+            DEC.empty_prefix(self.cfg)
         last_logits, kv, n_real = self._run_suffix(tokens, c, prefix_kv)
         self.kv_store.pages = self.kv_store.write_span(
             self.kv_store.pages, table_row, jnp.int32(c),
-            jnp.int32(n_real), {n: kv[n][:, 0] for n in ("k", "v")})
+            jnp.int32(n_real), {n: a[:, 0] for n, a in kv.items()})
         if scfg.prefix_caching:
             self.manager.commit(uid)
         first = self._first_token(last_logits)
@@ -380,14 +442,12 @@ class LLMExecutor(Executor):
 
     def _prefill_contiguous(self, uid, slot, tokens) -> PrefillResult:
         plen = len(tokens)
-        empty = {n: jnp.zeros((self.cfg.n_layers, 1, 0, self.cfg.n_kv,
-                               self.cfg.d_head), jnp.bfloat16)
-                 for n in ("k", "v")}
-        last_logits, kv, n_real = self._run_suffix(tokens, 0, empty)
+        last_logits, kv, n_real = self._run_suffix(
+            tokens, 0, DEC.empty_prefix(self.cfg))
         self.caches["kv"] = {
             n: self.caches["kv"][n].at[:, slot, :plen].set(
-                kv[n][:, 0, :plen].astype(self.caches["kv"][n].dtype))
-            for n in ("k", "v")}
+                a[:, 0, :plen].astype(self.caches["kv"][n].dtype))
+            for n, a in kv.items()}
         first = self._first_token(last_logits)
         self._tokens[uid] = [first]
         self.slots[slot] = _Resident(uid)
@@ -494,10 +554,14 @@ class LLMExecutor(Executor):
                     if r is not None else
                     np.zeros((self.blocks_per_seq,), np.int32)
                     for r in self.slots]))
+            args = (self.params, self.cur_tok, self.kv_store.pages, tables,
+                    self.pos)
             with trace.span("dispatch", cat="llm"):
-                logits, self.kv_store.pages = self._decode_fn(
-                    self.params, self.cur_tok, self.kv_store.pages,
-                    tables, self.pos)
+                if self.moe_assignments is None:
+                    logits, self.kv_store.pages = self._decode_fn(*args)
+                else:
+                    logits, self.kv_store.pages, self.moe_assignments = \
+                        self._decode_fn(*args, self.moe_assignments)
         with trace.span("sample", cat="llm"):
             nxt = self._sample(logits[:, -1])
             self.pos = self.pos + 1

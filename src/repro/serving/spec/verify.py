@@ -86,17 +86,15 @@ class VerifyWorker:
                 mgr.table_array(shadow, ex.blocks_per_seq))
             prefix_kv = ex.kv_store.gather(
                 ex.kv_store.pages, table_row[None, :c // bs]) if c else \
-                {n: jnp.zeros((ex.cfg.n_layers, 1, 0, ex.cfg.n_kv,
-                               ex.cfg.d_head), jnp.bfloat16)
-                 for n in ("k", "v")}
+                DEC.empty_prefix(ex.cfg)
             sb = _bucket(n_real, bs)
             padded = np.zeros((1, sb), np.int32)
             padded[0, :n_real] = suffix
             fn = ex._suffix_fn(c, sb)       # shares prefill's jit cache
-            logits, kv = fn(ex.params, jnp.asarray(padded), prefix_kv)
+            logits, kv, _ = fn(ex.params, jnp.asarray(padded), prefix_kv)
             ex.kv_store.pages = ex.kv_store.write_span(
                 ex.kv_store.pages, table_row, jnp.int32(c),
-                jnp.int32(n_real), {n: kv[n][:, 0] for n in ("k", "v")})
+                jnp.int32(n_real), {n: a[:, 0] for n, a in kv.items()})
         except Exception:
             mgr.free(shadow)
             raise

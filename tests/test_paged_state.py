@@ -372,7 +372,8 @@ def _serve(params, cfg, scfg, prompts=_PROMPTS):
 
 
 @pytest.mark.parametrize("name,layers", [
-    ("llama3_2_1b", 1), ("deepseek_moe_16b", 2), ("mamba2_780m", 1)])
+    ("llama3_2_1b", 1), ("deepseek_moe_16b", 2), ("mamba2_780m", 1),
+    ("moonlight_16b_a3b", 2)])
 def test_paged_bit_identical_to_contiguous(name, layers):
     params, cfg = _model(name, layers)
     kw = dict(n_slots=2, max_new_tokens=4, max_len=64, block_size=8)
