@@ -127,7 +127,9 @@ class LLMExecutor(Executor):
         self.params, self.cfg, self.scfg = params, cfg, scfg
         self.is_ssm = cfg.family == "ssm"
         self.slots: list = [None] * scfg.n_slots       # resident Requests
-        self.pos = jnp.zeros((scfg.n_slots,), jnp.int32)
+        # each slot's next write position, kept on the host only: the
+        # host sets every value, so reading one costs no device round trip
+        self.pos = np.zeros((scfg.n_slots,), np.int32)
         self.cur_tok = jnp.zeros((scfg.n_slots, 1), jnp.int32)
         self._tokens: dict[int, list[int]] = {}        # uid -> output tokens
         self._prompts: dict[int, np.ndarray] = {}      # uid -> prompt tokens
@@ -321,14 +323,16 @@ class LLMExecutor(Executor):
         return tokens_generated
 
     def _step_tokens(self) -> dict[int, list[int]]:
-        """One engine step's new tokens per live slot.  The base decode
-        loop emits exactly one; `SpecExecutor` overrides this with the
-        propose/verify/accept cycle (1 .. k+1 tokens per slot)."""
+        """One engine step's new tokens per live slot, brought to the
+        host in one transfer.  The base decode loop emits exactly one;
+        `SpecExecutor` overrides this with the propose/verify/accept
+        cycle (1 .. k+1 tokens per slot)."""
         nxt = self.decode()
         with self.obs.trace.span("sync", cat="llm"):
-            out = {i: [int(nxt[i])]
+            host = np.asarray(nxt)
+            out = {i: [int(host[i])]
                    for i, r in enumerate(self.slots) if r is not None}
-        self._count_reads("sync", len(out))
+        self._count_reads("sync")
         return out
 
     def _count_reads(self, site: str, n: int = 1) -> None:
@@ -389,7 +393,7 @@ class LLMExecutor(Executor):
         self.obs.metrics.counter(
             "prefill_tokens_total", "prompt tokens by whether the prefix "
             "cache served them").inc(res.tokens_computed, source="computed")
-        self.pos = self.pos.at[slot].set(plen)
+        self.pos[slot] = plen
         self.cur_tok = self.cur_tok.at[slot, 0].set(res.first_token)
         return res
 
@@ -536,15 +540,16 @@ class LLMExecutor(Executor):
         """One jitted decode step for every slot; returns the sampled
         next token per slot (junk rows for empty slots)."""
         trace = self.obs.trace
+        pos = self._device_pos()
         if not self.scfg.paged:
             with trace.span("dispatch", cat="llm"):
                 logits, self.caches = self._decode_fn(
-                    self.params, self.cur_tok, self.caches, self.pos)
+                    self.params, self.cur_tok, self.caches, pos)
         elif self.is_ssm:
             with trace.span("dispatch", cat="llm"):
                 logits, self.state_store.pages = self._decode_fn(
                     self.params, self.cur_tok, self.state_store.pages,
-                    self._slot_bids, self.pos)
+                    self._slot_bids, pos)
         else:
             with trace.span("cow", cat="llm"):
                 self._cow_for_decode()
@@ -555,7 +560,7 @@ class LLMExecutor(Executor):
                     np.zeros((self.blocks_per_seq,), np.int32)
                     for r in self.slots]))
             args = (self.params, self.cur_tok, self.kv_store.pages, tables,
-                    self.pos)
+                    pos)
             with trace.span("dispatch", cat="llm"):
                 if self.moe_assignments is None:
                     logits, self.kv_store.pages = self._decode_fn(*args)
@@ -564,7 +569,7 @@ class LLMExecutor(Executor):
                         self._decode_fn(*args, self.moe_assignments)
         with trace.span("sample", cat="llm"):
             nxt = self._sample(logits[:, -1])
-            self.pos = self.pos + 1
+            self.pos += 1
             self.cur_tok = nxt[:, None]
         return nxt
 
@@ -572,16 +577,19 @@ class LLMExecutor(Executor):
         """Make every live slot's write-target block exclusively owned
         (fires only after forks / prefix sharing into the write block)."""
         pairs = []
-        n_reads = 0
         for i, r in enumerate(self.slots):
             if r is None:
                 continue
             pair = self.manager.ensure_writable(r.uid, int(self.pos[i]))
-            n_reads += 1
             if pair is not None:
                 pairs.append(pair)
-        self._count_reads("cow", n_reads)
         self.kv_store.apply_copies(pairs)
+
+    def _device_pos(self) -> jax.Array:
+        """The slot positions as a device array.  JAX may read a host
+        buffer after the call it was handed to has returned, so it gets a
+        copy that later writes to ``self.pos`` cannot reach."""
+        return jnp.asarray(self.pos.copy())
 
     # -- engine failure paths ------------------------------------------------
 
@@ -616,7 +624,7 @@ class LLMExecutor(Executor):
         built from the same ``(params, cfg, scfg)`` continues decoding
         bit-identically.
         """
-        tree: dict = {"pos": self.pos, "cur_tok": self.cur_tok,
+        tree: dict = {"pos": self._device_pos(), "cur_tok": self.cur_tok,
                       "rng_key": self._key}
         if self.scfg.paged:
             if self.is_ssm:
@@ -644,7 +652,7 @@ class LLMExecutor(Executor):
 
     def restore(self, tree: dict, meta: dict) -> None:
         """Load a :meth:`snapshot` into this executor (same config)."""
-        self.pos = jnp.asarray(np.asarray(tree["pos"]), jnp.int32)
+        self.pos = np.array(tree["pos"], np.int32)
         self.cur_tok = jnp.asarray(np.asarray(tree["cur_tok"]), jnp.int32)
         self._key = jnp.asarray(np.asarray(tree["rng_key"]), jnp.uint32)
         if self.scfg.paged:
@@ -689,7 +697,7 @@ class LLMExecutor(Executor):
         self.slots[dst] = _Resident(new_uid)
         self._tokens[new_uid] = list(self._tokens[uid])
         self._prompts[new_uid] = self._prompts[uid]
-        self.pos = self.pos.at[dst].set(self.pos[src])
+        self.pos[dst] = self.pos[src]
         self.cur_tok = self.cur_tok.at[dst].set(self.cur_tok[src])
         return dst
 
@@ -701,7 +709,7 @@ class LLMExecutor(Executor):
     def _release(self, slot: int) -> None:
         req = self.slots[slot]
         self.slots[slot] = None
-        self.pos = self.pos.at[slot].set(0)      # empty slots write to NULL
+        self.pos[slot] = 0      # empty slots write to NULL
         self.cur_tok = self.cur_tok.at[slot, 0].set(0)
         self._prompts.pop(req.uid, None)
         if self.scfg.paged and not self.is_ssm and \

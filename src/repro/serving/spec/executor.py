@@ -158,7 +158,6 @@ class SpecExecutor(LLMExecutor):
         k = min(self._adaptive.k(),
                 self.scfg.max_new_tokens - m - 1,       # emit <= k+1 more
                 self.scfg.max_len - 1 - int(self.pos[slot]))
-        self._count_reads("cow")
         if cap is not None:
             k = min(k, cap)
         return max(k, 0)
@@ -169,7 +168,6 @@ class SpecExecutor(LLMExecutor):
         committed = np.concatenate(
             [self._prompts[uid], np.asarray(toks[:-1], np.int64)])
         pos = int(self.pos[slot])
-        self._count_reads("cow")
         full = np.concatenate([committed, [cur]])
 
         with self.obs.trace.span("spec_propose", tid=uid, cat="spec", k=k):
@@ -208,7 +206,7 @@ class SpecExecutor(LLMExecutor):
             "accepted proposals per verify step",
             buckets=COUNT_BUCKETS).observe(j)
 
-        self.pos = self.pos.at[slot].set(pos + j + 1)
+        self.pos[slot] = pos + j + 1
         self.cur_tok = self.cur_tok.at[slot, 0].set(emitted[-1])
         return emitted
 
@@ -222,11 +220,12 @@ class SpecExecutor(LLMExecutor):
         mask = np.zeros((self.scfg.n_slots,), bool)
         mask[subset] = True
         maskj = jnp.asarray(mask)
+        pos = self._device_pos()
         if self.is_ssm:
             bids = jnp.where(maskj, self._slot_bids, 0)
             logits, self.state_store.pages = self._decode_fn(
                 self.params, self.cur_tok, self.state_store.pages,
-                bids, self.pos)
+                bids, pos)
         else:
             pairs = []
             for i in subset:
@@ -234,7 +233,6 @@ class SpecExecutor(LLMExecutor):
                     self.slots[i].uid, int(self.pos[i]))
                 if pair is not None:
                     pairs.append(pair)
-            self._count_reads("cow", len(subset))
             self.kv_store.apply_copies(pairs)
             tables = np.stack([
                 self.manager.table_array(self.slots[i].uid,
@@ -243,9 +241,9 @@ class SpecExecutor(LLMExecutor):
                 for i in range(self.scfg.n_slots)])
             logits, self.kv_store.pages = self._decode_fn(
                 self.params, self.cur_tok, self.kv_store.pages,
-                jnp.asarray(tables), self.pos)
+                jnp.asarray(tables), pos)
         nxt = self._sample(logits[:, -1])
-        self.pos = jnp.where(maskj, self.pos + 1, self.pos)
+        self.pos[mask] += 1
         self.cur_tok = jnp.where(maskj[:, None], nxt[:, None],
                                  self.cur_tok)
         self._count_reads("sync")
